@@ -20,7 +20,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.common import pytree_dataclass
+from repro.common import fl_gain_sums, pytree_dataclass
 from repro.core.functions.base import SetFunction
 from repro.core.sources import (
     DenseSource,
@@ -84,7 +84,9 @@ class FacilityLocation(SetFunction):
             from repro.kernels import ops
 
             return ops.fl_gains(self.sim, state.curmax)
-        return jnp.maximum(self.sim - state.curmax[:, None], 0.0).sum(axis=0)
+        # the Pallas kernel's fixed order, not .sum(0): bit-identical across
+        # waves and backends (see tiled_sum)
+        return fl_gain_sums(self.sim, state.curmax)
 
     def gain_backend(self) -> FLPallasSweep | None:
         from repro.core.optimizers.backends import kernel_enabled
@@ -93,7 +95,7 @@ class FacilityLocation(SetFunction):
 
     def gains_at(self, state: FLState, idxs: jax.Array) -> jax.Array:
         cols = self.sim[:, idxs]  # (|U|, k)
-        return jnp.maximum(cols - state.curmax[:, None], 0.0).sum(axis=0)
+        return fl_gain_sums(cols, state.curmax)
 
     def update(self, state: FLState, j: jax.Array) -> FLState:
         return FLState(
@@ -124,7 +126,7 @@ class FLMFPallasSweep:
             return ops.fl_gains(src.sim, state.curmax)
         return ops.flmf_gains(
             src.x, src.y, src.xx, src.yy, state.curmax,
-            metric=src.metric, rbf_sigma=src.rbf_sigma,
+            metric=src.metric, rbf_sigma=src.rbf_sigma, col_ids=src.col_ids,
         )
 
     def partial_sweep(
@@ -137,7 +139,7 @@ class FLMFPallasSweep:
             return ops.fl_gains_at(src.sim, state.curmax, idx)
         return ops.flmf_gains_at(
             src.x, src.y, src.xx, src.yy, state.curmax, idx,
-            metric=src.metric, rbf_sigma=src.rbf_sigma,
+            metric=src.metric, rbf_sigma=src.rbf_sigma, col_ids=src.col_ids,
         )
 
 

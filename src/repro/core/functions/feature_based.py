@@ -10,7 +10,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.common import get_concave, pytree_dataclass
+from repro.common import get_concave, pytree_dataclass, tiled_sum
 from repro.core.functions.base import SetFunction
 
 
@@ -70,22 +70,22 @@ class FeatureBased(SetFunction):
     def init_state(self) -> FBState:
         return FBState(acc=jnp.zeros((self.feats.shape[1],), jnp.float32))
 
-    def gains(self, state: FBState) -> jax.Array:
+    def _gain_sums(self, feats: jax.Array, acc: jax.Array) -> jax.Array:
+        # elementwise-multiply + the Pallas kernel's fixed add order rather
+        # than `@ w` or `.sum(-1)`: both lower to shape-dependent
+        # accumulation orders (see tiled_sum), which would break served ==
+        # sequential and Pallas == XLA bit-identity.
         g = get_concave(self.concave)
-        base = g(state.acc)  # (F,)
-        # elementwise-multiply + reduce rather than `@ w`: XLA lowers a
-        # batched matvec through a different GEMM tiling than the single
-        # instance, which shifts gains by ulps under vmap; the reduce form is
-        # bit-stable, keeping batched/sharded serving identical to single
-        # `maximize` calls.
-        diff = g(state.acc[None, :] + self.feats) - base[None, :]
-        return (diff * self.w[None, :]).sum(axis=-1)
+        return tiled_sum(
+            lambda f, a, w: (g(a + f) - g(a)) * w,
+            (feats, acc, self.w), (1, 0, 0), 1,
+        )
+
+    def gains(self, state: FBState) -> jax.Array:
+        return self._gain_sums(self.feats, state.acc)
 
     def gains_at(self, state: FBState, idxs: jax.Array) -> jax.Array:
-        g = get_concave(self.concave)
-        base = g(state.acc)
-        diff = g(state.acc[None, :] + self.feats[idxs]) - base[None, :]
-        return (diff * self.w[None, :]).sum(axis=-1)
+        return self._gain_sums(self.feats[idxs], state.acc)
 
     def update(self, state: FBState, j: jax.Array) -> FBState:
         return FBState(acc=state.acc + self.feats[j])

@@ -50,30 +50,10 @@ from repro.common import NEG_INF
 _INT_MAX = jnp.int32(2**31 - 1)
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map moved out of jax.experimental in newer releases and the
-    ``check_rep`` kwarg was renamed ``check_vma``; dispatch on what exists."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )
-
-
-def _axis_size(a: str):
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(a)
-    return jax.lax.psum(1, a)  # older jax: no lax.axis_size
-
-
 def _flat_axis_index(axes: Sequence[str]) -> jax.Array:
     idx = jnp.zeros((), jnp.int32)
     for a in axes:
-        idx = idx * _axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -96,7 +76,7 @@ def distributed_fl_greedy(
     in_spec = P(row_axes if row_axes else None, col_axes)
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(in_spec,),
         out_specs=(P(), P()),
@@ -167,7 +147,7 @@ def distributed_stochastic_fl_greedy(
     in_spec = P(row_axes if row_axes else None, col_axes)
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(in_spec, P()),
         out_specs=(P(), P()),
@@ -231,7 +211,7 @@ def distributed_flqmi_greedy(
     col_axes = tuple(col_axes)
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(None, col_axes), P(col_axes)),
         out_specs=(P(), P()),
@@ -1003,7 +983,7 @@ def sharded_batched_greedy(
     B, n = valid.shape
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             rule.part_specs(batch_axes, col_axes),
@@ -1146,7 +1126,7 @@ def sharded_batched_lazy(
     levels = _screen_levels(n, screen_k)
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             rule.part_specs(batch_axes, col_axes),

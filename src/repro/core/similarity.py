@@ -18,13 +18,21 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.common import DISTANCE_METRICS
+
 _METRICS = ("dot", "cosine", "euclidean", "rbf")
+
+
+def _matmul_t(x: jax.Array, y: jax.Array) -> jax.Array:
+    """x @ y.T in full float32.  A bare ``@`` runs one bf16 pass on a TPU
+    (about 4e-3 relative error), enough to reorder near-tied greedy gains."""
+    return jnp.matmul(x, y.T, precision=jax.lax.Precision.HIGHEST)
 
 
 def pairwise_sq_dists(x: jax.Array, y: jax.Array) -> jax.Array:
     xx = jnp.sum(x * x, axis=1)[:, None]
     yy = jnp.sum(y * y, axis=1)[None, :]
-    d2 = xx + yy - 2.0 * (x @ y.T)
+    d2 = xx + yy - 2.0 * _matmul_t(x, y)
     return jnp.maximum(d2, 0.0)
 
 
@@ -44,8 +52,9 @@ def create_kernel(
     """
     if metric not in _METRICS:
         raise ValueError(f"unknown metric {metric!r}; choose from {_METRICS}")
+    square = y is None
     x = jnp.asarray(x)
-    y = x if y is None else jnp.asarray(y)
+    y = x if square else jnp.asarray(y)
 
     if use_pallas:
         from repro.kernels import ops
@@ -53,6 +62,10 @@ def create_kernel(
         sim = ops.similarity(x, y, metric=metric, rbf_sigma=rbf_sigma)
     else:
         sim = _reference_kernel(x, y, metric, rbf_sigma)
+    if square and metric in DISTANCE_METRICS:
+        # a point is at distance 0 from itself: similarity 1 exactly, not
+        # the float32 residue of xx + yy - 2 x.y (repro.common.exact_self_distance)
+        sim = jnp.fill_diagonal(sim, 1.0, inplace=False)
 
     if mode == "dense":
         return sim
@@ -65,11 +78,11 @@ def create_kernel(
 
 def _reference_kernel(x, y, metric, rbf_sigma):
     if metric == "dot":
-        return x @ y.T
+        return _matmul_t(x, y)
     if metric == "cosine":
         xn = x / jnp.maximum(jnp.linalg.norm(x, axis=1, keepdims=True), 1e-12)
         yn = y / jnp.maximum(jnp.linalg.norm(y, axis=1, keepdims=True), 1e-12)
-        return 0.5 * (1.0 + xn @ yn.T)  # shift to [0, 1]
+        return 0.5 * (1.0 + _matmul_t(xn, yn))  # shift to [0, 1]
     d2 = pairwise_sq_dists(x, y)
     if metric == "euclidean":
         return 1.0 / (1.0 + jnp.sqrt(d2))

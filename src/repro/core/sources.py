@@ -46,7 +46,13 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.common import NEG_INF, pytree_dataclass
+from repro.common import (
+    DISTANCE_METRICS,
+    NEG_INF,
+    exact_self_distance,
+    fl_gain_sums,
+    pytree_dataclass,
+)
 
 # Column-tile width of the streamed feature sweeps.  Static so every
 # serving bucket of the same source traces to the same per-column
@@ -118,10 +124,12 @@ def _tree_dot(x: jax.Array, yt: jax.Array) -> jax.Array:
 
 
 @functools.lru_cache(maxsize=None)
-def _sim_block(metric: str, inv_two_sigma_sq: float, masked: bool):
-    """Cached bit-stable similarity block for (metric, sigma, masked)."""
+def _sim_block(metric: str, inv_two_sigma_sq: float, masked: bool, ided: bool):
+    """Cached bit-stable similarity block for (metric, sigma, masked,
+    ided: column ids name which columns are rows, see
+    ``repro.common.exact_self_distance``)."""
 
-    def base(x, yt, xx, yyt, *labels):
+    def base(x, yt, xx, yyt, *extra):
         acc = _tree_dot(x, yt)
         if metric == "dot":
             s = acc
@@ -129,12 +137,14 @@ def _sim_block(metric: str, inv_two_sigma_sq: float, masked: bool):
             s = 0.5 * (1.0 + acc)  # rows arrive pre-normalized
         else:
             d2 = jnp.maximum(xx[:, None] + yyt[None, :] - 2.0 * acc, 0.0)
+            if ided:
+                d2 = exact_self_distance(d2, 0, extra[0])
             if metric == "euclidean":
                 s = 1.0 / (1.0 + jnp.sqrt(d2))
             else:  # rbf
                 s = jnp.exp(-d2 * inv_two_sigma_sq)
         if masked:
-            rl, lt = labels
+            rl, lt = extra[-2:]
             s = jnp.where(rl[:, None] == lt[None, :], s, 0.0)
         return _fence(s)
 
@@ -158,6 +168,13 @@ def _colsum_rule(axis_size, in_batched, t):
     return _map_unbatched(_colsum, axis_size, in_batched, (t,))
 
 
+def _pad_to(a: jax.Array | None, n_to: int, value=0) -> jax.Array | None:
+    """``a`` extended along axis 0 to ``n_to`` entries of ``value``."""
+    if a is None:
+        return None
+    return jnp.full((n_to,) + a.shape[1:], value, a.dtype).at[: a.shape[0]].set(a)
+
+
 def _pad_axis(a: jax.Array, mult: int, axis: int, value=0.0) -> jax.Array:
     pad = (-a.shape[axis]) % mult
     if pad == 0:
@@ -172,7 +189,9 @@ class FeatureSource:
     """Features + metric: sim(i, j) = metric(x_i, y_j), computed on demand.
 
     ``x`` are the represented-set rows, ``y`` the candidate columns (the
-    same array for symmetric sources — build with :func:`feature_source`).
+    same array for symmetric sources — build with :func:`feature_source`,
+    which also sets ``col_ids`` so that a point's distance to itself is
+    exactly 0, see :func:`repro.common.exact_self_distance`).
     For cosine the rows arrive PRE-normalized (zero-norm rows clamp to the
     zero vector, landing on the 0.5 midpoint after the [0, 1] shift, same
     as ``core/similarity.py``); ``xx``/``yy`` are squared row norms feeding
@@ -186,6 +205,9 @@ class FeatureSource:
     yy: jax.Array  # (n_cols,)
     row_labels: jax.Array | None
     col_labels: jax.Array | None
+    # (n_cols,) int32: the row each column is the same point as (-1: none);
+    # None when rows and columns are different point sets
+    col_ids: jax.Array | None
     metric: str
     rbf_sigma: float | None
     d: int
@@ -197,51 +219,111 @@ class FeatureSource:
         sigma = self.rbf_sigma if self.rbf_sigma is not None else float(self.d) ** 0.5
         return 1.0 / (2.0 * sigma * sigma)
 
-    def _sim_cols(self, yt, yyt, lt) -> jax.Array:
-        """Similarity block (n_rows, tc) against the column tile ``yt``."""
-        block = _sim_block(self.metric, self._inv_two_sigma_sq(), lt is not None)
-        if lt is None:
-            return block(self.x, yt, self.xx, yyt)
-        return block(self.x, yt, self.xx, yyt, self.row_labels, lt)
+    def _sim_cols(self, yt, yyt, lt, ct) -> jax.Array:
+        """Similarity block (n_rows, tc) against the column tile ``yt``
+        (``lt`` its labels, ``ct`` its column ids; either may be None)."""
+        ided = ct is not None and self.metric in DISTANCE_METRICS
+        block = _sim_block(
+            self.metric, self._inv_two_sigma_sq(), lt is not None, ided
+        )
+        args = [self.x, yt, self.xx, yyt]
+        if ided:
+            args.append(ct)
+        if lt is not None:
+            args += [self.row_labels, lt]
+        return block(*args)
 
     def _col_tiles(self):
-        """(y, yy, labels) reshaped to (nt, TILE, ...) for a lax.scan."""
+        """(y, yy, labels, col ids) reshaped to (nt, TILE, ...) for a
+        lax.scan; absent labels / ids stay None."""
         y = _pad_axis(self.y, TILE, 0)
-        yy = _pad_axis(self.yy, TILE, 0)
         nt = y.shape[0] // TILE
-        tiles = (y.reshape(nt, TILE, -1), yy.reshape(nt, TILE))
-        if self.col_labels is None:
-            return tiles + (None,)
-        lab = _pad_axis(self.col_labels, TILE, 0, value=-1)
-        return tiles + (lab.reshape(nt, TILE),)
+
+        def tiles(a, value=0.0):
+            if a is None:
+                return None
+            a = _pad_axis(a, TILE, 0, value=value)
+            return a.reshape((nt, TILE) + a.shape[1:])
+
+        return (
+            tiles(y),
+            tiles(self.yy),
+            tiles(self.col_labels, value=-1),
+            tiles(self.col_ids, value=-1),
+        )
 
     def _scan_cols(self, per_tile, init):
-        """Stream column tiles through ``per_tile(carry, sim_block, extras)``.
+        """Stream column tiles through ``per_tile(carry, sim_block)``.
 
-        ``extras`` is the (yt, yyt, lt, col_mask) tuple of the tile; the
-        scan carries ``init`` and stacks per-tile outputs.  Peak live bytes:
-        one (n_rows, TILE) block, never (n_rows, n_cols).
+        The scan carries ``init`` and stacks per-tile outputs.  Peak live
+        bytes: one (n_rows, TILE) block, never (n_rows, n_cols).
         """
-        yt_all, yyt_all, lt_all = self._col_tiles()
 
-        def body(carry, args):
-            if lt_all is None:
-                yt, yyt = args
-                lt = None
-            else:
-                yt, yyt, lt = args
-            s = self._sim_cols(yt, yyt, lt)
-            return per_tile(carry, s)
+        def body(carry, tile):
+            return per_tile(carry, self._sim_cols(*tile))
 
-        xs = (yt_all, yyt_all) if lt_all is None else (yt_all, yyt_all, lt_all)
-        return jax.lax.scan(body, init, xs)
+        return jax.lax.scan(body, init, self._col_tiles())
+
+    # -- column operations ---------------------------------------------------
+    # Everything indexed by column (y, yy, col_labels, col_ids) moves
+    # together here, so callers never touch the per-column arrays one by one.
+    def take_cols(self, idx: jax.Array) -> "FeatureSource":
+        """The source over candidate columns ``idx`` (in range); rows kept."""
+
+        def take(a):
+            return None if a is None else jnp.take(a, idx, axis=0)
+
+        return dataclasses.replace(
+            self, y=take(self.y), yy=take(self.yy), col_labels=take(self.col_labels),
+            col_ids=take(self.col_ids), n_cols=int(idx.shape[0]),
+        )
+
+    def pad_cols(self, n_to: int) -> "FeatureSource":
+        """Zero-feature columns appended up to ``n_to``; their labels and
+        ids are -1, which match nothing."""
+        return dataclasses.replace(
+            self, y=_pad_to(self.y, n_to), yy=_pad_to(self.yy, n_to),
+            col_labels=_pad_to(self.col_labels, n_to, -1),
+            col_ids=_pad_to(self.col_ids, n_to, -1), n_cols=n_to,
+        )
+
+    def square_pad(self, n_to: int) -> "FeatureSource":
+        """A symmetric source padded on both axes to ``n_to``: the padded
+        columns, and as many rows, the same zero-feature points."""
+        p = self.pad_cols(n_to)
+        return dataclasses.replace(
+            p, x=p.y, xx=p.yy, row_labels=p.col_labels, n_rows=n_to
+        )
+
+    def append_cols(self, y: jax.Array, yy: jax.Array) -> "FeatureSource":
+        """New candidate columns (new points, id -1) after the existing
+        ones; rows kept."""
+        ids = self.col_ids
+        if ids is not None:
+            ids = jnp.concatenate([ids, jnp.full(yy.shape, -1, jnp.int32)])
+        return dataclasses.replace(
+            self, y=jnp.concatenate([self.y, y]), yy=jnp.concatenate([self.yy, yy]),
+            col_ids=ids, n_cols=self.n_cols + int(y.shape[0]),
+        )
+
+    def append_points(self, x: jax.Array, xx: jax.Array) -> "FeatureSource":
+        """A symmetric source grown by the points ``x`` (squared norms
+        ``xx``), each a new row and its own column."""
+        x = jnp.concatenate([self.x, x])
+        xx = jnp.concatenate([self.xx, xx])
+        n = int(x.shape[0])
+        return dataclasses.replace(
+            self, x=x, y=x, xx=xx, yy=xx, col_ids=jnp.arange(n, dtype=jnp.int32),
+            n_rows=n, n_cols=n,
+        )
 
     # -- source contract -----------------------------------------------------
     def col(self, j: jax.Array) -> jax.Array:
         """sim(i, j) for every row i, shape (n_rows,)."""
         safe = jnp.clip(j, 0, self.n_cols - 1)
         lt = None if self.col_labels is None else self.col_labels[safe][None]
-        return self._sim_cols(self.y[safe][None], self.yy[safe][None], lt)[:, 0]
+        ct = None if self.col_ids is None else self.col_ids[safe][None]
+        return self._sim_cols(self.y[safe][None], self.yy[safe][None], lt, ct)[:, 0]
 
     def col_sums(self) -> jax.Array:
         _, out = self._scan_cols(lambda c, s: (c, s.sum(axis=0)), None)
@@ -269,19 +351,7 @@ class FeatureSource:
         # sweep, so every similarity dot is computed at the same matmul
         # width — subset gains match the full sweep's bit-for-bit (a
         # width-k contraction can differ in the last ulps)
-        safe = jnp.clip(idx, 0, self.n_cols - 1)
-        sub = dataclasses.replace(
-            self,
-            y=jnp.take(self.y, safe, axis=0),
-            yy=jnp.take(self.yy, safe),
-            col_labels=(
-                None
-                if self.col_labels is None
-                else jnp.take(self.col_labels, safe)
-            ),
-            n_cols=int(idx.shape[0]),
-        )
-        g = sub.fl_gains(curmax)
+        g = self.take_cols(jnp.clip(idx, 0, self.n_cols - 1)).fl_gains(curmax)
         return jnp.where(idx >= 0, g, NEG_INF)
 
     def masked_rowmax(self, mask: jax.Array) -> jax.Array:
@@ -337,9 +407,11 @@ def feature_source(
         x32 = x32 / jnp.maximum(jnp.linalg.norm(x32, axis=1, keepdims=True), 1e-12)
     xx = (x32 * x32).sum(axis=1)
     row_labels = None if labels is None else jnp.asarray(labels, jnp.int32)
+    col_ids = None
     if y is None:
         y32, yy = x32, xx
         clab = row_labels if col_labels is None else jnp.asarray(col_labels, jnp.int32)
+        col_ids = jnp.arange(x32.shape[0], dtype=jnp.int32)
     else:
         y32 = jnp.asarray(y, jnp.float32)
         if metric == "cosine":
@@ -357,6 +429,7 @@ def feature_source(
         yy=yy,
         row_labels=row_labels,
         col_labels=clab,
+        col_ids=col_ids,
         metric=metric,
         rbf_sigma=rbf_sigma,
         d=int(x32.shape[1]),
@@ -485,7 +558,8 @@ def knn_from_features(
             xx=src.xx[lo : lo + batch],
             n_rows=min(batch, n - lo),
         )
-        sim = block._sim_cols(block.y, block.yy, None)  # (b, n)
+        # column ids shifted so that local row i is global row lo + i
+        sim = block._sim_cols(block.y, block.yy, None, src.col_ids - lo)  # (b, n)
         w, i = jax.lax.top_k(sim, k)
         idx_out.append(i.astype(jnp.int32))
         w_out.append(w)
@@ -514,12 +588,13 @@ class DenseSource:
         return jnp.diagonal(self.sim)
 
     def fl_gains(self, curmax: jax.Array) -> jax.Array:
-        return jnp.maximum(self.sim - curmax[:, None], 0.0).sum(axis=0)
+        # the Pallas FL kernel's order (FacilityLocation.gains does the same)
+        return fl_gain_sums(self.sim, curmax)
 
     def fl_gains_at(self, curmax: jax.Array, idx: jax.Array) -> jax.Array:
         safe = jnp.clip(idx, 0, self.n_cols - 1)
         cols = jnp.take(self.sim, safe, axis=1)
-        g = jnp.maximum(cols - curmax[:, None], 0.0).sum(axis=0)
+        g = fl_gain_sums(cols, curmax)
         return jnp.where(idx >= 0, g, NEG_INF)
 
     def masked_rowmax(self, mask: jax.Array) -> jax.Array:

@@ -8,8 +8,12 @@ For the Feature-Based function (paper §2.3.3) with memoized feature mass
 with g a concave scalarizer (sqrt / log1p / inverse).  XLA materializes the
 (n, F) concave intermediate in HBM; this kernel streams each (BN x BF) tile
 of the feature matrix through VMEM once and fuses add -> concave -> weighted
-row-reduce in-register on the VPU, accumulating the F strips into a (1, BN)
-output block.
+row-reduce in-register on the VPU.  The (BN, BLOCK) output block is
+revisited across F strips as the fp32 accumulator: each (BN, BF) tile of
+weighted terms is added to it BLOCK columns at a time, in column order
+(``repro.common.add_blocks``), and the wrapper sums its BLOCK columns with
+``tree_sum``.  That is exactly ``repro.common.tiled_sum``, the XLA sweep's
+order, so the Pallas and XLA routes return the same bits.
 
 grid = (n/BN, F/BF), F innermost; the concave name is a static kernel param.
 
@@ -28,7 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.common import NEG_INF, get_concave
+from repro.common import BLOCK, NEG_INF, add_blocks, get_concave, tree_sum
 
 BN = 256  # candidates per tile
 BF = 256  # features per tile
@@ -45,7 +49,7 @@ def _fb_kernel(x_ref, acc_ref, w_ref, out_ref, *, concave):
     x = x_ref[...].astype(jnp.float32)  # (BN, BF)
     a = acc_ref[...].astype(jnp.float32)  # (1, BF)
     w = w_ref[...].astype(jnp.float32)  # (1, BF)
-    out_ref[...] += ((g(a + x) - g(a)) * w).sum(axis=1)[None, :]
+    out_ref[...] = add_blocks(out_ref[...], (g(a + x) - g(a)) * w, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("concave", "interpret", "bn", "bf"))
@@ -59,7 +63,9 @@ def fb_gains_pallas(
     bf: int = BF,
 ) -> jax.Array:
     """feats (n, F) non-negative scores, acc (F,) memoized mass, w (F,)
-    weights -> gains (n,) fp32.  Padded features get w = 0 so contribute 0."""
+    weights -> gains (n,) fp32.  Padded features get w = 0 so contribute 0.
+    ``bf`` must be a multiple of BLOCK to sum in the XLA order (a
+    narrower ``bf`` accumulates in blocks of its own width)."""
     n, F = feats.shape
     pad_n = (-n) % bn
     pad_f = (-F) % bf
@@ -67,6 +73,7 @@ def fb_gains_pallas(
     ap = jnp.pad(acc.astype(jnp.float32)[None, :], ((0, 0), (0, pad_f)))
     wp = jnp.pad(w.astype(jnp.float32)[None, :], ((0, 0), (0, pad_f)))
     npn, npf = xp.shape
+    lanes = min(bf, BLOCK)
     out = pl.pallas_call(
         functools.partial(_fb_kernel, concave=concave),
         grid=(npn // bn, npf // bf),
@@ -75,11 +82,11 @@ def fb_gains_pallas(
             pl.BlockSpec((1, bf), lambda j, f: (0, f)),
             pl.BlockSpec((1, bf), lambda j, f: (0, f)),
         ],
-        out_specs=pl.BlockSpec((1, bn), lambda j, f: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((1, npn), jnp.float32),
+        out_specs=pl.BlockSpec((bn, lanes), lambda j, f: (j, 0)),
+        out_shape=jax.ShapeDtypeStruct((npn, lanes), jnp.float32),
         interpret=interpret,
     )(xp, ap, wp)
-    return out[0, :n]
+    return tree_sum(out[:n], axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("concave", "interpret"))

@@ -8,8 +8,12 @@ kernel streams each S tile through VMEM exactly once and fuses
 subtract→relu→column-reduce in-register, so the op stays at the 1x-HBM-read
 roofline of S itself.
 
-grid = (N/BN, U/BU) with U innermost; the (1, BN) output block is revisited
-across U steps and used as the fp32 accumulator.
+grid = (N/BN, U/BU) with U innermost.  The (BLOCK, BN) output block is
+revisited across U steps as the fp32 accumulator: each (BU, BN) tile of
+relu terms is added to it BLOCK rows at a time, in row order
+(``repro.common.add_blocks``), and the wrapper sums its BLOCK rows with
+``tree_sum``.  That is exactly ``repro.common.tiled_sum``, the XLA FL
+sweep's order, so the Pallas and XLA routes return the same bits.
 
 ``fl_gains_at_pallas`` is the masked-subset entry point (the lazy engines'
 ``partial_sweep`` contract): an XLA gather of the K requested columns feeds
@@ -27,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.common import NEG_INF
+from repro.common import BLOCK, NEG_INF, add_blocks, tree_sum
 
 BU = 256  # represented-set rows per tile
 BN = 512  # candidates per tile
@@ -44,7 +48,7 @@ def _fl_gains_kernel(s_ref, cm_ref, out_ref):
 
     s = s_ref[...].astype(jnp.float32)  # (BU, BN)
     cm = cm_ref[...].astype(jnp.float32)  # (BU, 1)
-    out_ref[...] += jnp.maximum(s - cm, 0.0).sum(axis=0)[None, :]
+    out_ref[...] = add_blocks(out_ref[...], jnp.maximum(s - cm, 0.0), 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bu", "bn"))
@@ -55,7 +59,9 @@ def fl_gains_pallas(
     bu: int = BU,
     bn: int = BN,
 ) -> jax.Array:
-    """sim (u, n), curmax (u,) -> gains (n,) in fp32."""
+    """sim (u, n), curmax (u,) -> gains (n,) in fp32.  ``bu`` must be a
+    multiple of BLOCK to sum in the XLA order (a smaller ``bu``
+    accumulates in blocks of its own height)."""
     u, n = sim.shape
     pad_u = (-u) % bu
     pad_n = (-n) % bn
@@ -66,6 +72,7 @@ def fl_gains_pallas(
     )
     up, npad = sp.shape
     grid = (npad // bn, up // bu)
+    rows = min(bu, BLOCK)
     out = pl.pallas_call(
         _fl_gains_kernel,
         grid=grid,
@@ -73,11 +80,11 @@ def fl_gains_pallas(
             pl.BlockSpec((bu, bn), lambda j, i: (i, j)),
             pl.BlockSpec((bu, 1), lambda j, i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bn), lambda j, i: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((1, npad), jnp.float32),
+        out_specs=pl.BlockSpec((rows, bn), lambda j, i: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((rows, npad), jnp.float32),
         interpret=interpret,
     )(sp, cmp_)
-    return out[0, :n]
+    return tree_sum(out[:, :n], axis=0)
 
 
 def _subset_tile(k: int, cap: int) -> int:

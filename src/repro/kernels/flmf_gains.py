@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common import NEG_INF
+from repro.common import DISTANCE_METRICS, NEG_INF, exact_self_distance
 from repro.kernels.fl_gains import _PAD_CM
 
 BU = 256  # represented-set rows per tile
@@ -46,9 +46,10 @@ BK = 512  # feature-contraction strip
 
 
 def _flmf_kernel(
-    x_ref, y_ref, xx_ref, yy_ref, cm_ref, out_ref, acc_ref,
-    *, metric, inv_two_sigma_sq, nd,
+    x_ref, y_ref, xx_ref, yy_ref, cm_ref, *refs,
+    metric, inv_two_sigma_sq, nd, ided,
 ):
+    ids_ref, out_ref, acc_ref = refs if ided else (None,) + refs
     u = pl.program_id(1)
     kd = pl.program_id(2)
 
@@ -63,7 +64,9 @@ def _flmf_kernel(
     x = x_ref[...].astype(jnp.float32)  # (BU, BK)
     y = y_ref[...].astype(jnp.float32)  # (BN, BK)
     acc_ref[...] += jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, y, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,  # full f32, not one bf16 pass
     )
 
     @pl.when(kd == nd - 1)
@@ -77,6 +80,8 @@ def _flmf_kernel(
             xx = xx_ref[...].astype(jnp.float32)  # (BU, 1)
             yy = yy_ref[...].astype(jnp.float32)  # (1, BN)
             d2 = jnp.maximum(xx + yy - 2.0 * acc, 0.0)
+            if ided:
+                d2 = exact_self_distance(d2, u * acc.shape[0], ids_ref[...])
             if metric == "euclidean":
                 s = 1.0 / (1.0 + jnp.sqrt(d2))
             else:  # rbf
@@ -101,9 +106,14 @@ def flmf_gains_pallas(
     bu: int = BU,
     bn: int = BN,
     bk: int = BK,
+    col_ids: jax.Array | None = None,
 ) -> jax.Array:
     """x (u, d), y (n, d), squared norms xx (u,) / yy (n,), curmax (u,)
-    -> gains (n,) fp32, without materializing the (u, n) similarity."""
+    -> gains (n,) fp32, without materializing the (u, n) similarity.
+
+    ``col_ids`` (n,) int32 names the row of ``x`` each candidate is the same
+    point as (-1: none), so distance metrics see exactly 0 there
+    (:func:`repro.common.exact_self_distance`)."""
     u, d = x.shape
     n = y.shape[0]
     xp = jnp.pad(x.astype(jnp.float32), ((0, (-u) % bu), (0, (-d) % bk)))
@@ -119,26 +129,36 @@ def flmf_gains_pallas(
     nd = dp // bk
     sigma = rbf_sigma if rbf_sigma is not None else float(d) ** 0.5
     grid = (npad // bn, up // bu, nd)
+    ided = col_ids is not None and metric in DISTANCE_METRICS
+    in_specs = [
+        pl.BlockSpec((bu, bk), lambda j, i, k: (i, k)),
+        pl.BlockSpec((bn, bk), lambda j, i, k: (j, k)),
+        pl.BlockSpec((bu, 1), lambda j, i, k: (i, 0)),
+        pl.BlockSpec((1, bn), lambda j, i, k: (0, j)),
+        pl.BlockSpec((bu, 1), lambda j, i, k: (i, 0)),
+    ]
+    args = [xp, yp, xxp, yyp, cmp_]
+    if ided:
+        in_specs.append(pl.BlockSpec((1, bn), lambda j, i, k: (0, j)))
+        args.append(
+            jnp.pad(col_ids.astype(jnp.int32)[None, :], ((0, 0), (0, (-n) % bn)),
+                    constant_values=-1)
+        )
     out = pl.pallas_call(
         functools.partial(
             _flmf_kernel,
             metric=metric,
             inv_two_sigma_sq=1.0 / (2.0 * sigma * sigma),
             nd=nd,
+            ided=ided,
         ),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((bu, bk), lambda j, i, k: (i, k)),
-            pl.BlockSpec((bn, bk), lambda j, i, k: (j, k)),
-            pl.BlockSpec((bu, 1), lambda j, i, k: (i, 0)),
-            pl.BlockSpec((1, bn), lambda j, i, k: (0, j)),
-            pl.BlockSpec((bu, 1), lambda j, i, k: (i, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bn), lambda j, i, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, npad), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bu, bn), jnp.float32)],
         interpret=interpret,
-    )(xp, yp, xxp, yyp, cmp_)
+    )(*args)
     return out[0, :n]
 
 
@@ -157,9 +177,11 @@ def flmf_gains_at_pallas(
     interpret: bool = False,
     bu: int = BU,
     bk: int = BK,
+    col_ids: jax.Array | None = None,
 ) -> jax.Array:
     """Masked-subset sweep: gains at the gathered candidates ``idx`` (k,)
     int32 -> (k,) fp32; slots with idx < 0 are padding and return NEG_INF.
+    ``col_ids`` as in :func:`flmf_gains_pallas`, over all n candidates.
 
     Unlike the dense subset sweeps, the candidate tile stays at the
     full-sweep width BN: the similarity dot is recomputed here, and a
@@ -178,5 +200,6 @@ def flmf_gains_at_pallas(
         bu=bu,
         bn=BN,
         bk=bk,
+        col_ids=None if col_ids is None else jnp.take(col_ids, safe),
     )
     return jnp.where(idx >= 0, out, NEG_INF)

@@ -62,7 +62,9 @@ def _gcmf_kernel(
     yj = yj_ref[...].astype(jnp.float32)  # (BJ, BKD)
     yk = yk_ref[...].astype(jnp.float32)  # (BKC, BKD)
     acc_ref[...] += jax.lax.dot_general(
-        yj, yk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        yj, yk, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,  # full f32, not one bf16 pass
     )
 
     @pl.when(kd == nd - 1)

@@ -45,19 +45,23 @@ def gc_gains_at(sim, selmask, total, lam, idx):
     return gc_gains_at_pallas(sim, selmask, total, lam, idx, interpret=_interpret())
 
 
-def flmf_gains(x, y, xx, yy, curmax, metric: str = "dot", rbf_sigma: float | None = None):
+def flmf_gains(
+    x, y, xx, yy, curmax, metric: str = "dot", rbf_sigma: float | None = None,
+    col_ids=None,
+):
     return flmf_gains_pallas(
         x, y, xx, yy, curmax, metric=metric, rbf_sigma=rbf_sigma,
-        interpret=_interpret(),
+        interpret=_interpret(), col_ids=col_ids,
     )
 
 
 def flmf_gains_at(
-    x, y, xx, yy, curmax, idx, metric: str = "dot", rbf_sigma: float | None = None
+    x, y, xx, yy, curmax, idx, metric: str = "dot", rbf_sigma: float | None = None,
+    col_ids=None,
 ):
     return flmf_gains_at_pallas(
         x, y, xx, yy, curmax, idx, metric=metric, rbf_sigma=rbf_sigma,
-        interpret=_interpret(),
+        interpret=_interpret(), col_ids=col_ids,
     )
 
 

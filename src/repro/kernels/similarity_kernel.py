@@ -37,7 +37,9 @@ def _sim_kernel(x_ref, y_ref, xx_ref, yy_ref, out_ref, *, metric, inv_two_sigma_
     x = x_ref[...].astype(jnp.float32)  # (BN, BK)
     y = y_ref[...].astype(jnp.float32)  # (BM, BK)
     out_ref[...] += jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, y, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,  # full f32, not one bf16 pass
     )
 
     @pl.when(k == nk - 1)

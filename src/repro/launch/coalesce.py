@@ -244,13 +244,7 @@ def _pad_source_cols(src, n_to: int):
     import jax.numpy as jnp
 
     if isinstance(src, FeatureSource):
-        n = src.n_cols
-        y = jnp.zeros((n_to, src.y.shape[1]), src.y.dtype).at[:n].set(src.y)
-        yy = jnp.zeros((n_to,), src.yy.dtype).at[:n].set(src.yy)
-        clab = src.col_labels
-        if clab is not None:
-            clab = jnp.full((n_to,), -1, jnp.int32).at[:n].set(clab)
-        return _dc.replace(src, y=y, yy=yy, col_labels=clab, n_cols=n_to)
+        return src.pad_cols(n_to)
     if isinstance(src, KnnSource):
         # meta-only: the scatter target grows; indices/weights are untouched,
         # so real candidates' gains are bit-identical for free
@@ -277,16 +271,7 @@ def _pad_source_square(src, n_to: int):
     import jax.numpy as jnp
 
     if isinstance(src, FeatureSource):
-        n = src.n_cols
-        y = jnp.zeros((n_to, src.y.shape[1]), src.y.dtype).at[:n].set(src.y)
-        yy = jnp.zeros((n_to,), src.yy.dtype).at[:n].set(src.yy)
-        lab = src.col_labels
-        if lab is not None:
-            lab = jnp.full((n_to,), -1, jnp.int32).at[:n].set(lab)
-        return _dc.replace(
-            src, x=y, y=y, xx=yy, yy=yy, row_labels=lab, col_labels=lab,
-            n_rows=n_to, n_cols=n_to,
-        )
+        return src.square_pad(n_to)
     if isinstance(src, KnnSource):
         n = src.n_rows
         indices = jnp.full((n_to, src.k), -1, jnp.int32).at[:n].set(src.indices)

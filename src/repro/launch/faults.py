@@ -232,12 +232,10 @@ def active_plan() -> FaultPlan | None:
 def _tracing() -> bool:
     # fault boundaries are host-side only: a check reached through a jit
     # trace must not fire, or firing order would depend on jit-cache state
-    try:
-        import jax.core as _jc
+    # (and on the coalescer's eval_shape layout memo)
+    import jax.core as _jc
 
-        return not _jc.trace_state_clean()
-    except Exception:
-        return False
+    return not _jc.trace_ctx.is_top_level()
 
 
 def check(site: str, **attrs) -> None:

@@ -1077,6 +1077,9 @@ def main():
 
     import jax
 
+    from repro.common import enable_compile_cache
+
+    enable_compile_cache()
     mesh = None
     if a.mesh:
         b, d = (int(v) for v in a.mesh.lower().split("x"))

@@ -237,16 +237,9 @@ def _extend_fl_mf(fn: FacilityLocationMF, rows) -> FacilityLocationMF:
         d32 = d32 / jnp.maximum(jnp.linalg.norm(d32, axis=1, keepdims=True), 1e-12)
     dd = (d32 * d32).sum(axis=1)
     if _is_symmetric(src):
-        x = jnp.concatenate([src.x, d32], axis=0)
-        xx = jnp.concatenate([src.xx, dd], axis=0)
-        new_src = dataclasses.replace(
-            src, x=x, y=x, xx=xx, yy=xx,
-            n_rows=int(x.shape[0]), n_cols=int(x.shape[0]),
-        )
+        new_src = src.append_points(d32, dd)
     else:  # fixed represented rows, growing candidate columns
-        y = jnp.concatenate([src.y, d32], axis=0)
-        yy = jnp.concatenate([src.yy, dd], axis=0)
-        new_src = dataclasses.replace(src, y=y, yy=yy, n_cols=int(y.shape[0]))
+        new_src = src.append_cols(d32, dd)
     return dataclasses.replace(fn, src=new_src, n=new_src.n_cols)
 
 
@@ -269,17 +262,7 @@ def _restrict_fl_mf(fn: FacilityLocationMF, active) -> FacilityLocationMF:
             n_cols=int(active.shape[0]),
         )
     elif isinstance(src, FeatureSource):
-        sub = dataclasses.replace(
-            src,
-            y=jnp.take(src.y, active, axis=0),
-            yy=jnp.take(src.yy, active),
-            col_labels=(
-                None
-                if src.col_labels is None
-                else jnp.take(src.col_labels, active)
-            ),
-            n_cols=int(active.shape[0]),
-        )
+        sub = src.take_cols(jnp.asarray(active))
     else:
         raise NotImplementedError(
             "session restriction of FacilityLocationMF needs a FeatureSource "

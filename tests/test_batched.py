@@ -331,3 +331,34 @@ def test_stop_flag_behaviour_on_modular_function(rng):
     never = naive_greedy(fn, n, False, False)
     assert len(never.as_list()) == n  # budget exhausted, negatives included
     np.testing.assert_allclose(float(never.value), w.sum(), rtol=1e-6)
+
+
+@pytest.mark.parametrize("concave", ["sqrt", "log", "inverse"])
+def test_feature_based_batch_width_bit_stable(concave, rng):
+    """FeatureBased served in a wave of one and a wave of two (rows padded
+    to the n-bucket) equals sequential solve bit for bit: ids, gains and
+    n_evals.  Its gain's sum over features is an add tree, not a reduce
+    whose order XLA picks per program."""
+    from repro.core import SelectionSpec, solve
+    from repro.launch.serve import SelectionServer
+
+    specs = [
+        SelectionSpec(
+            FeatureBased.from_features(
+                rng.uniform(0, 1, size=(n, 24)).astype(np.float32), concave=concave
+            ),
+            budget,
+        )
+        for n, budget in ((40, 6), (56, 4))
+    ]
+    sequential = [solve(s) for s in specs]
+    one = SelectionServer().select(specs[:1])
+    two = SelectionServer().select(specs)
+    assert two[0].wave_size == 2
+    for got, want in ((one[0], sequential[0]), (two[0], sequential[0]),
+                      (two[1], sequential[1])):
+        for field in ("order", "gains", "n_evals"):
+            assert (
+                np.asarray(getattr(got.result, field)).tobytes()
+                == np.asarray(getattr(want, field)).tobytes()
+            ), field

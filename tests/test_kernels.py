@@ -550,3 +550,34 @@ def test_partial_sweep_routes_through_kernel_backends():
     r1 = lazy_greedy(plain, 6)
     r2 = lazy_greedy(fused, 6)
     assert list(np.asarray(r1.order)) == list(np.asarray(r2.order))
+
+
+@pytest.mark.parametrize("family", ["fl", "fb"])
+@pytest.mark.parametrize("shape", [(8, 40), (33, 300), (300, 600), (513, 1025)])
+def test_pallas_sweep_equals_xla_sweep_bit_for_bit(family, shape, rng):
+    """The XLA gain sweep and its Pallas kernel sum in one fixed order
+    (repro.common.tiled_sum), so a breaker's Pallas -> XLA reroute returns
+    the Pallas route's bits: full sweep and gathered subset alike."""
+    from repro.core import FacilityLocation, FeatureBased
+
+    u, n = shape
+    idx = jnp.asarray([n - 1, -1, 0, n // 2], jnp.int32)
+    if family == "fl":
+        S = rng.uniform(0, 1, size=(u, n)).astype(np.float32)
+        fns = [FacilityLocation.from_kernel(S, use_kernel=k) for k in (True, False)]
+        state = fns[0].init_state()
+        state = fns[0].update(state, jnp.int32(n // 3))
+    else:  # u candidates, n features
+        X = rng.uniform(0, 1, size=(u, n)).astype(np.float32)
+        fns = [FeatureBased.from_features(X, use_kernel=k) for k in (True, False)]
+        state = fns[0].update(fns[0].init_state(), jnp.int32(u // 3))
+        idx = jnp.clip(idx, -1, u - 1)
+    backend = fns[0].gain_backend()
+    assert backend is not None and fns[1].gain_backend() is None
+    full = (backend.full_sweep(fns[0], state), fns[1].gains(state))
+    at = (backend.partial_sweep(fns[0], state, idx), fns[1].gains_at(state, idx))
+    assert np.asarray(full[0]).tobytes() == np.asarray(full[1]).tobytes()
+    live = np.asarray(idx) >= 0
+    assert (
+        np.asarray(at[0])[live].tobytes() == np.asarray(at[1])[live].tobytes()
+    )

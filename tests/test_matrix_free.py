@@ -199,6 +199,38 @@ def test_flmf_pallas_matches_ref(rng, metric):
     _close(got_at[[0, 1, 3]], np.asarray(want)[[3, 69, 17]])
 
 
+@pytest.mark.parametrize("metric", ("euclidean", "rbf"))
+def test_self_distance_is_exactly_zero(rng, metric):
+    """A point against itself is at distance 0 on every path — dense
+    kernel (XLA and Pallas), streamed column, fused sweep — even where
+    xx + yy - 2 x.y leaves a large float32 residue (|x|^2 ~ 10^3)."""
+    x = 10.0 + make_points(rng, 45, 12)  # common offset: |x|^2 ~ 10^3
+    for use_pallas in (False, True):
+        S = create_kernel(x, metric=metric, use_pallas=use_pallas)
+        np.testing.assert_array_equal(np.diagonal(np.asarray(S)), 1.0)
+    src = feature_source(x, metric=metric)
+    for j in (0, 7, 44):
+        assert float(src.col(jnp.asarray(j))[j]) == 1.0
+    # the fused sweep at an empty state: each candidate's gain is its column
+    # sum, self term included, so it must match the exact dense kernel
+    S = np.asarray(create_kernel(x, metric=metric))
+    curmax = np.zeros(45, np.float32)
+    want = S.sum(axis=0)
+    got = ops.flmf_gains(
+        src.x, src.y, src.xx, src.yy, curmax, metric=metric, col_ids=src.col_ids
+    )
+    _close(got, want)
+    _close(src.fl_gains(jnp.asarray(curmax)), want)
+    idx = jnp.asarray([3, -1, 44], jnp.int32)
+    got_at = np.asarray(
+        ops.flmf_gains_at(
+            src.x, src.y, src.xx, src.yy, curmax, idx,
+            metric=metric, col_ids=src.col_ids,
+        )
+    )
+    _close(got_at[[0, 2]], want[[3, 44]])
+
+
 @pytest.mark.parametrize("metric", METRICS + ("euclidean",))
 def test_gcmf_pallas_matches_ref(rng, metric):
     n, d = 70, 12

@@ -1,0 +1,179 @@
+"""Compile every Pallas entry point behind ``repro.kernels.ops`` for a TPU
+v5e at deployment shapes, without a chip.
+
+The TPU compiler is installed even where no chip is attached: a described
+``v5e:2x2`` topology gives devices to compile for.  This catches what
+interpret mode cannot — tiles the Mosaic compiler refuses, scoped-VMEM
+overruns, programs that do not fit the chip's 16 GB of HBM — at no chip
+time.  Nothing runs, so these tests say nothing about results or speed.
+
+Shapes are the CIFAR-10 per-class coreset deployment (10 classes of 5,000
+items, 512-wide embeddings): dense sweeps at the served n-bucket 8192 (FL
+keeps its 5,000 represented rows), the FL sweep also vmapped over a wave of
+10 classes, and matrix-free sweeps over the whole 50,000 x 512 pool (with
+and without the column ids of a symmetric source).
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library at a time, and every test worker imports
+this file.
+"""
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels.disp_gains import dmin_gains_pallas, dsum_gains_pallas
+from repro.kernels.fb_gains import fb_gains_at_pallas, fb_gains_pallas
+from repro.kernels.fl_gains import fl_gains_at_pallas, fl_gains_pallas
+from repro.kernels.flmf_gains import flmf_gains_at_pallas, flmf_gains_pallas
+from repro.kernels.gc_gains import gc_gains_at_pallas, gc_gains_pallas
+from repro.kernels.gcmf_gains import gcmf_gains_at_pallas, gcmf_gains_pallas
+from repro.kernels.sc_gains import psc_gains_pallas, sc_gains_pallas
+from repro.kernels.similarity_kernel import similarity_pallas
+
+HBM_BYTES = 16 * 10**9  # one TPU v5e chip
+U = 5_000  # represented rows of one class
+N = 8_192  # served n-bucket of a 5,000-item class
+B = 10  # classes per served wave
+POOL = 50_000  # whole training set, matrix-free
+D = 512  # embedding width (ResNet-18 penultimate layer)
+F = 512  # feature / concept columns of the modular-coverage families
+K = 512  # gathered subset of a lazy step
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler, or its library is held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# name -> (function of the arrays, [(shape, dtype), ...]); interpret=False
+# compiles the kernel for the chip instead of the Python interpreter
+CASES = {
+    "fl": (partial(fl_gains_pallas, interpret=False), [((U, N), "f"), ((U,), "f")]),
+    "fl_at": (
+        partial(fl_gains_at_pallas, interpret=False),
+        [((U, N), "f"), ((U,), "f"), ((K,), "i")],
+    ),
+    "fl_wave": (
+        jax.vmap(partial(fl_gains_pallas, interpret=False)),
+        [((B, U, N), "f"), ((B, U), "f")],
+    ),
+    "flmf": (
+        partial(flmf_gains_pallas, metric="euclidean", interpret=False),
+        [((POOL, D), "f"), ((POOL, D), "f"), ((POOL,), "f"), ((POOL,), "f"),
+         ((POOL,), "f")],
+    ),
+    "flmf_at": (
+        partial(flmf_gains_at_pallas, metric="euclidean", interpret=False),
+        [((POOL, D), "f"), ((POOL, D), "f"), ((POOL,), "f"), ((POOL,), "f"),
+         ((POOL,), "f"), ((K,), "i")],
+    ),
+    # a symmetric source (from_features(x)) marks its columns' own rows
+    "flmf_ided": (
+        lambda x, y, xx, yy, cm, ids: flmf_gains_pallas(
+            x, y, xx, yy, cm, metric="euclidean", interpret=False, col_ids=ids
+        ),
+        [((POOL, D), "f"), ((POOL, D), "f"), ((POOL,), "f"), ((POOL,), "f"),
+         ((POOL,), "f"), ((POOL,), "i")],
+    ),
+    "flmf_at_ided": (
+        lambda x, y, xx, yy, cm, idx, ids: flmf_gains_at_pallas(
+            x, y, xx, yy, cm, idx, metric="euclidean", interpret=False, col_ids=ids
+        ),
+        [((POOL, D), "f"), ((POOL, D), "f"), ((POOL,), "f"), ((POOL,), "f"),
+         ((POOL,), "f"), ((K,), "i"), ((POOL,), "i")],
+    ),
+    "gc": (
+        partial(gc_gains_pallas, interpret=False),
+        [((N, N), "f"), ((N,), "f"), ((N,), "f"), ((), "f")],
+    ),
+    "gc_at": (
+        partial(gc_gains_at_pallas, interpret=False),
+        [((N, N), "f"), ((N,), "f"), ((N,), "f"), ((), "f"), ((K,), "i")],
+    ),
+    "gcmf": (
+        partial(gcmf_gains_pallas, metric="euclidean", interpret=False),
+        [((POOL, D), "f"), ((POOL, D), "f"), ((POOL,), "f"), ((POOL,), "f"),
+         ((POOL,), "f"), ((POOL,), "f"), ((POOL,), "f"), ((), "f")],
+    ),
+    "gcmf_at": (
+        partial(gcmf_gains_at_pallas, metric="euclidean", interpret=False),
+        [((POOL, D), "f"), ((POOL,), "f"), ((POOL,), "f"), ((POOL,), "f"),
+         ((POOL,), "f"), ((), "f"), ((K,), "i")],
+    ),
+    "fb": (
+        partial(fb_gains_pallas, interpret=False),
+        [((N, F), "f"), ((F,), "f"), ((F,), "f")],
+    ),
+    "fb_at": (
+        partial(fb_gains_at_pallas, interpret=False),
+        [((N, F), "f"), ((F,), "f"), ((F,), "f"), ((K,), "i")],
+    ),
+    "sc": (
+        partial(sc_gains_pallas, interpret=False),
+        [((N, F), "f"), ((F,), "f"), ((F,), "f")],
+    ),
+    "psc": (
+        partial(psc_gains_pallas, interpret=False),
+        [((N, F), "f"), ((F,), "f"), ((F,), "f")],
+    ),
+    "dsum": (
+        partial(dsum_gains_pallas, interpret=False),
+        [((N, N), "f"), ((N,), "f")],
+    ),
+    "dmin": (
+        partial(dmin_gains_pallas, interpret=False),
+        [((N, N), "f"), ((N,), "f"), ((), "i"), ((), "f")],
+    ),
+    "similarity": (
+        partial(similarity_pallas, metric="euclidean", interpret=False),
+        [((U, D), "f"), ((U, D), "f")],
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
+    fn, specs = CASES[name]
+    dtypes = {"f": jnp.float32, "i": jnp.int32}
+    args = [
+        jax.ShapeDtypeStruct(shape, dtypes[dt], sharding=one_chip)
+        for shape, dt in specs
+    ]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), "no Pallas kernel in the program"
+    mem = compiled.memory_analysis()
+    used = (
+        mem.argument_size_in_bytes
+        + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes
+        - mem.alias_size_in_bytes
+    )
+    assert used < HBM_BYTES, f"{name} needs {used} bytes on one 16 GB chip"
