@@ -38,8 +38,9 @@ efficiency" item this module closed).
 """
 from __future__ import annotations
 
+import contextlib
 from functools import partial
-from typing import Sequence
+from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -189,17 +190,47 @@ class BatchedEngine:
         stop_if_zero: bool = True,
         stop_if_negative: bool = True,
         max_budget: int | None = None,
+        span: Callable = contextlib.nullcontext,
     ) -> list[GreedyResult]:
-        """Solve the resident batch through the optimizer registry.
+        """Solve the resident batch through the optimizer registry:
+        :meth:`enqueue`, then :meth:`fetch`.
 
         This is the typed engine path behind ``solve()`` and the serving
-        dispatch: the optimizer (an :class:`OptimizerSpec`, or a name built
-        into one) carries its validated hyperparameters, and the registry
-        supplies the batched / sharded execution hook — an optimizer without
-        one is rejected here with the batched-capable set named, never
-        mid-trace.  ``max_budget`` optionally raises the static loop bound
-        above max(budgets) — serving uses bucketed bounds so waves with
-        different budget mixes share one compiled program.
+        dispatch.  ``span(name)`` is a context-manager factory that brackets
+        the two steps, ``"engine.launch"`` and ``"engine.fetch"``; the
+        serving layer passes its span recorder (nothing here reads a clock).
+        """
+        with span("engine.launch"):
+            launched = self.enqueue(
+                budget,
+                optimizer,
+                stop_if_zero=stop_if_zero,
+                stop_if_negative=stop_if_negative,
+                max_budget=max_budget,
+            )
+        with span("engine.fetch"):
+            return self.fetch(launched)
+
+    def enqueue(
+        self,
+        budget: int | Sequence[int],
+        optimizer: OptimizerSpec | str = "NaiveGreedy",
+        *,
+        stop_if_zero: bool = True,
+        stop_if_negative: bool = True,
+        max_budget: int | None = None,
+    ) -> tuple[GreedyResult, list[int]]:
+        """Dispatch the batch's selection program and return at once: the
+        batched :class:`GreedyResult` of device arrays, and the per-instance
+        budgets that :meth:`fetch` slices it by.
+
+        The optimizer (an :class:`OptimizerSpec`, or a name built into one)
+        carries its validated hyperparameters, and the registry supplies the
+        batched / sharded execution hook — an optimizer without one is
+        rejected here with the batched-capable set named, never mid-trace.
+        ``max_budget`` optionally raises the static loop bound above
+        max(budgets) — serving uses bucketed bounds so waves with different
+        budget mixes share one compiled program.
         """
         opt = OptimizerSpec(optimizer) if not isinstance(optimizer, OptimizerSpec) else optimizer
         defn = resolve_optimizer(opt.name)
@@ -259,6 +290,12 @@ class BatchedEngine:
                 stop_if_negative,
                 **opt.params,
             )
+        return res, budgets
+
+    def fetch(self, launched: tuple[GreedyResult, list[int]]) -> list[GreedyResult]:
+        """Wait for an :meth:`enqueue`-d program and slice its answer per
+        instance."""
+        res, budgets = launched
         # one transfer for the whole batch, then host-side slicing — B tiny
         # device slices would dominate small-query serving latency
         order, gains, evals, value = jax.device_get(

@@ -78,6 +78,7 @@ def dsum_gains_pallas(
         out_specs=pl.BlockSpec((1, bj), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, npj), jnp.float32),
         interpret=interpret,
+        name="dsum_gains",  # the compiled custom call's name, read by the trace
     )(dp, mp)
     return out[0, :n]
 
@@ -135,5 +136,6 @@ def dmin_gains_pallas(
         out_specs=pl.BlockSpec((1, bj), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, npj), jnp.float32),
         interpret=interpret,
+        name="dmin_gains",  # the compiled custom call's name, read by the trace
     )(cnt, cur, dp, mp)
     return out[0, :n]

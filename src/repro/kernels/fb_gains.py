@@ -85,6 +85,7 @@ def fb_gains_pallas(
         out_specs=pl.BlockSpec((bn, lanes), lambda j, f: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((npn, lanes), jnp.float32),
         interpret=interpret,
+        name="fb_gains",  # the compiled custom call's name, read by the trace
     )(xp, ap, wp)
     return tree_sum(out[:n], axis=1)
 

@@ -83,6 +83,7 @@ def fl_gains_pallas(
         out_specs=pl.BlockSpec((rows, bn), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((rows, npad), jnp.float32),
         interpret=interpret,
+        name="fl_gains",  # the compiled custom call's name, read by the trace
     )(sp, cmp_)
     return tree_sum(out[:, :n], axis=0)
 

@@ -158,6 +158,7 @@ def flmf_gains_pallas(
         out_shape=jax.ShapeDtypeStruct((1, npad), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bu, bn), jnp.float32)],
         interpret=interpret,
+        name="flmf_gains",  # the compiled custom call's name, read by the trace
     )(*args)
     return out[0, :n]
 

@@ -96,6 +96,7 @@ def gc_gains_pallas(
         out_specs=pl.BlockSpec((1, bj), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, npj), jnp.float32),
         interpret=interpret,
+        name="gc_gains",  # the compiled custom call's name, read by the trace
     )(lam_s, sp, mp, tp)
     return out[0, :n]
 
@@ -163,5 +164,6 @@ def gc_gains_at_pallas(
         out_specs=pl.BlockSpec((1, bj), lambda j, kb: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, npj), jnp.float32),
         interpret=interpret,
+        name="gc_gains_at",  # the compiled custom call's name, read by the trace
     )(lam_s, sp, mp, tp, gp)
     return jnp.where(idx >= 0, out[0, :k], NEG_INF)

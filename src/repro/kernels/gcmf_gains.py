@@ -155,6 +155,7 @@ def gcmf_gains_pallas(
         out_shape=jax.ShapeDtypeStruct((1, jp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bj, bkc), jnp.float32)],
         interpret=interpret,
+        name="gcmf_gains",  # the compiled custom call's name, read by the trace
     )(lam_s, yjp, ykp, yyjp, yykp, mp, tp, dgp)
     return out[0, :j]
 

@@ -71,6 +71,7 @@ def sc_gains_pallas(
         out_specs=pl.BlockSpec((1, bn), lambda j, u: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, npn), jnp.float32),
         interpret=interpret,
+        name="sc_gains",  # the compiled custom call's name, read by the trace
     )(gp, cp, wp)
     return out[0, :n]
 
@@ -118,5 +119,6 @@ def psc_gains_pallas(
         out_specs=pl.BlockSpec((1, bn), lambda j, u: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, npn), jnp.float32),
         interpret=interpret,
+        name="psc_gains",  # the compiled custom call's name, read by the trace
     )(pp, wmp)
     return out[0, :n]

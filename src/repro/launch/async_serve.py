@@ -400,7 +400,7 @@ class AsyncSelectionServer:
             with self._cv:
                 if self._closed:
                     return
-                now = time.monotonic()
+                now = time.perf_counter()  # the clock of enqueue_t / deadline_t
                 due, wake_at = self._due_groups(now)
                 if not due:
                     timeout = None if wake_at is None else max(0.0, wake_at - now)

@@ -72,7 +72,8 @@ class SelectionRequest:
     """One enqueued query: a request id plus its :class:`SelectionSpec`.
 
     The request IS the spec — serving adds only routing identity (``rid``)
-    and arrival time (``enqueue_t``, monotonic, stamped at construction),
+    and arrival time (``enqueue_ns``, ``time.perf_counter_ns()`` stamped at
+    construction: the clock of the serving spans, ``launch/metrics.py``),
     which is what lets the coalescer, the batched engines, and the async
     front end all consume the same validated object unchanged.  The arrival
     stamp is what makes latency accounting truthful: a response reports the
@@ -82,7 +83,12 @@ class SelectionRequest:
 
     rid: int | str
     spec: SelectionSpec
-    enqueue_t: float = dataclasses.field(default_factory=time.monotonic)
+    enqueue_ns: int = dataclasses.field(default_factory=time.perf_counter_ns)
+
+    @property
+    def enqueue_t(self) -> float:
+        """Arrival in seconds on the ``time.perf_counter()`` clock."""
+        return self.enqueue_ns * 1e-9
 
     @property
     def fn(self):
@@ -95,7 +101,7 @@ class SelectionRequest:
 
     @property
     def deadline_t(self) -> Optional[float]:
-        """Absolute monotonic deadline (``enqueue_t + spec.deadline_s``), or
+        """Absolute deadline (``enqueue_t + spec.deadline_s``), or
         None when the request carries no deadline."""
         if self.spec.deadline_s is None:
             return None
@@ -388,6 +394,8 @@ class Wave:
     stop_if_zero: bool
     stop_if_negative: bool
     n_bucket: int
+    bytes_stacked: int  # nbytes of the padded fns' leaves, batch pads included
+    bytes_live: int  # nbytes of the real requests' own unpadded fns' leaves
 
     @property
     def batch_size(self) -> int:
@@ -480,6 +488,14 @@ def group_label(req: SelectionRequest, *, n_multiple: int = 1) -> str:
     )
 
 
+def _nbytes(fn) -> int:
+    """Bytes of a function pytree's leaves, from their shapes and dtypes."""
+    return sum(
+        int(np.prod(np.shape(leaf))) * np.result_type(leaf).itemsize
+        for leaf in jax.tree.leaves(fn)
+    )
+
+
 def waves_for_group(
     requests: Sequence[SelectionRequest],
     *,
@@ -521,6 +537,9 @@ def waves_for_group(
                 stop_if_zero=head.stop_if_zero,
                 stop_if_negative=head.stop_if_negative,
                 n_bucket=n_bucket,
+                # one group key: every padded fn has the same leaf shapes
+                bytes_stacked=b_total * _nbytes(fns[0]),
+                bytes_live=sum(_nbytes(r.spec.fn) for r in reqs),
             )
         )
     return waves

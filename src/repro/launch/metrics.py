@@ -1,13 +1,11 @@
-"""Structured serving metrics: bounded counters and histograms.
+"""Structured serving metrics: bounded counters, histograms and spans.
 
 The serving front door (`launch/serve.py` / `launch/async_serve.py`) used to
 keep an unbounded ``wave_seconds`` list — linear memory in flush count on a
-long-lived server — and reported nothing a scheduler could train on.  This
-module is the replacement: every aggregate is **bounded** (count / sum / min
-/ max plus a fixed-size uniform reservoir for percentiles), and the whole
-tree snapshots to one JSON-able dict consumed by ``benchmarks/serve_bench.py
---json``, the serve CLI, and — per ROADMAP item 4 — the future backend/mesh
-autotuner.
+long-lived server.  This module is the replacement: every aggregate is
+**bounded** (count / sum / min / max plus a fixed-size uniform reservoir for
+percentiles), and the whole tree snapshots to one JSON-able dict consumed by
+``benchmarks/serve_bench.py --json`` and the serve CLI.
 
 Schema (``ServerMetrics.snapshot()``)::
 
@@ -17,6 +15,8 @@ Schema (``ServerMetrics.snapshot()``)::
         "waves": int,           # engine dispatches
         "slots": int,           # engine slots incl. batch pads
         "padded_slots": int,    # batch-pad slots (pad waste)
+        "bytes_stacked": int,   # nbytes of the waves' padded functions
+        "bytes_live": int,      # nbytes of the real requests' own functions
         "rejections": int,      # submits refused by backpressure
         "flush_errors": int,    # dispatch errors (FlushError raised)
         "requeued": int,        # requests re-enqueued after a flush error
@@ -29,8 +29,8 @@ Schema (``ServerMetrics.snapshot()``)::
         "fallbacks_total": int, # waves served degraded (breaker open)
         "quarantined_total": int, # requests failed typed after N attempts
       },
-      "queue_s":  {count, sum, max, p50, p99},   # submit -> dispatch start
-      "wave_s":   {count, sum, max, p50, p99},   # one engine dispatch
+      "queue_s":  {count, sum, max, p50, p99},   # the serve.queue spans
+      "wave_s":   {count, sum, max, p50, p99},   # the serve.wave spans
       "queue_depth": {count, sum, max, p50, p99},# depth sampled at enqueue
       "delta_s":  {count, sum, max, p50, p99},   # session extend -> update
       "breakers": {"<label>": "closed|open|half_open", ...},
@@ -45,16 +45,35 @@ Schema (``ServerMetrics.snapshot()``)::
 Group labels are ``Family/n<bucket>/<Optimizer>`` — the same (family,
 n-bucket) keys the coalescer groups waves by, promoted to queue identity.
 
+Spans: :meth:`ServerMetrics.span` times one layer boundary of the served
+path (``serve.select`` / ``serve.submit`` / ``serve.drain`` /
+``serve.wave`` / ``engine.build`` / ``engine.launch`` / ``engine.fetch`` /
+``serve.demux``, plus ``serve.queue`` per request).  Each finished span —
+name, span id, parent span id, wave id, start and end in
+``time.perf_counter_ns()`` nanoseconds, attributes — lands in one bounded,
+process-wide ring (:data:`SPAN_CAPACITY` records, oldest dropped) that
+:func:`spans` reads.  Every span but ``serve.queue`` also enters a
+``jax.profiler.TraceAnnotation``, so a profiled run shows it on the host
+plane, on the device trace's clock.  Spans are always on.  Request arrival
+stamps (``SelectionRequest.enqueue_ns``) use the same clock.
+
 Thread-safety: increments and histogram records are guarded by one internal
-lock, so the flush thread, submitters, and a metrics scraper can interleave
-freely; ``snapshot()`` returns a detached copy.
+lock, the span ring by its own, so the flush thread, submitters, and a
+metrics scraper can interleave freely; ``snapshot()`` and :func:`spans`
+return detached copies.  Parent spans are tracked per thread.
 """
 from __future__ import annotations
 
+import collections
+import itertools
 import math
 import random
 import threading
+import time
 import zlib
+from typing import NamedTuple
+
+import jax
 
 
 def _seed_for(name: str) -> int:
@@ -64,7 +83,7 @@ def _seed_for(name: str) -> int:
     name decorrelates them while staying reproducible across runs."""
     return zlib.crc32(name.encode("utf-8"))
 
-__all__ = ["Reservoir", "Histogram", "ServerMetrics"]
+__all__ = ["Reservoir", "Histogram", "ServerMetrics", "Span", "SPAN_CAPACITY", "spans"]
 
 
 class Reservoir:
@@ -150,6 +169,8 @@ _COUNTERS = (
     "waves",
     "slots",
     "padded_slots",
+    "bytes_stacked",
+    "bytes_live",
     "rejections",
     "flush_errors",
     "requeued",
@@ -174,6 +195,111 @@ class _GroupMetrics:
         self.waves = 0
         self.queue_s = Histogram(reservoir_size, seed=_seed_for(f"{label}/queue_s"))
         self.wave_s = Histogram(reservoir_size, seed=_seed_for(f"{label}/wave_s"))
+
+
+# -- spans -----------------------------------------------------------------
+
+SPAN_CAPACITY = 65_536  # finished spans the process-wide ring keeps
+
+
+class Span(NamedTuple):
+    """One finished span.  Times are ``time.perf_counter_ns()``; a root span
+    has ``parent_id`` 0, a span outside any wave ``wave_id`` 0."""
+
+    name: str
+    span_id: int
+    parent_id: int
+    wave_id: int
+    start_ns: int
+    end_ns: int
+    attrs: dict
+
+    @property
+    def seconds(self) -> float:
+        return (self.end_ns - self.start_ns) * 1e-9
+
+
+class SpanRing:
+    """The newest ``capacity`` finished spans, oldest dropped first."""
+
+    _GUARDED_BY = {"_records": "_lock"}
+
+    def __init__(self, capacity: int = SPAN_CAPACITY):
+        self._lock = threading.Lock()
+        self._records: collections.deque = collections.deque(maxlen=capacity)
+
+    def add(self, span: Span) -> None:
+        with self._lock:
+            self._records.append(span)
+
+    def snapshot(self) -> list[Span]:
+        with self._lock:
+            return list(self._records)
+
+
+_RING = SpanRing()
+_SPAN_IDS = itertools.count(1)
+_WAVE_IDS = itertools.count(1)
+
+
+class _OpenSpans(threading.local):
+    def __init__(self):
+        self.stack: list = []  # this thread's open spans, innermost last
+
+
+_OPEN = _OpenSpans()
+
+
+def spans() -> list[Span]:
+    """Every span the process-wide ring still holds, oldest first."""
+    return _RING.snapshot()
+
+
+class ActiveSpan:
+    """An open span (the context manager :meth:`ServerMetrics.span`
+    returns).  Its parent is the thread's innermost open span, whose wave id
+    it inherits unless it opens a wave of its own; ``attrs`` may grow until
+    it closes, and ``seconds`` reads its duration once closed."""
+
+    __slots__ = (
+        "name", "span_id", "parent_id", "wave_id", "attrs",
+        "start_ns", "end_ns", "_annotation",
+    )
+
+    def __init__(self, name: str, wave_id: int, attrs: dict):
+        self.name = name
+        self.span_id = next(_SPAN_IDS)
+        self.parent_id = 0
+        self.wave_id = wave_id
+        self.attrs = attrs
+        self.start_ns = self.end_ns = 0
+        self._annotation = jax.profiler.TraceAnnotation(name)
+
+    def __enter__(self) -> "ActiveSpan":
+        stack = _OPEN.stack
+        if stack:
+            self.parent_id = stack[-1].span_id
+            self.wave_id = self.wave_id or stack[-1].wave_id
+        stack.append(self)
+        self._annotation.__enter__()
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.end_ns = time.perf_counter_ns()
+        self._annotation.__exit__(exc_type, exc, tb)
+        _OPEN.stack.pop()
+        if exc_type is not None:
+            self.attrs["error"] = exc_type.__name__
+        _RING.add(
+            Span(self.name, self.span_id, self.parent_id, self.wave_id,
+                 self.start_ns, self.end_ns, self.attrs)
+        )
+        return False
+
+    @property
+    def seconds(self) -> float:
+        return (self.end_ns - self.start_ns) * 1e-9
 
 
 class ServerMetrics:
@@ -220,8 +346,7 @@ class ServerMetrics:
 
     def observe_enqueue(self, label: str, depth: int) -> None:
         """One request admitted to ``label``'s queue, which now holds
-        ``depth`` requests (the depth histogram feeds the autotuner's
-        batching-pressure signal)."""
+        ``depth`` requests."""
         with self._lock:
             self.queue_depth.record(depth)
             self._group_locked(label)  # the group exists from first admission
@@ -234,13 +359,17 @@ class ServerMetrics:
         requests: int,
         slots: int,
         padded_slots: int,
+        bytes_stacked: int = 0,
+        bytes_live: int = 0,
     ) -> None:
-        """One engine dispatch for ``label``'s group."""
+        """One engine dispatch for ``label``'s group, ``wave_s`` long."""
         with self._lock:
             self.counters["waves"] += 1
             self.counters["requests"] += requests
             self.counters["slots"] += slots
             self.counters["padded_slots"] += padded_slots
+            self.counters["bytes_stacked"] += bytes_stacked
+            self.counters["bytes_live"] += bytes_live
             self.wave_s.record(wave_s)
             g = self._group_locked(label)
             g.waves += 1
@@ -250,8 +379,8 @@ class ServerMetrics:
     def observe_served(
         self, label: str, queue_s: float, *, deadline_missed: bool = False
     ) -> None:
-        """One request answered: it waited ``queue_s`` before its wave's
-        dispatch began."""
+        """One request answered: it waited ``queue_s`` (its ``serve.queue``
+        span) before its wave's dispatch began."""
         with self._lock:
             self.queue_s.record(queue_s)
             self._group_locked(label).queue_s.record(queue_s)
@@ -266,6 +395,25 @@ class ServerMetrics:
             self.counters["session_deltas"] += 1
             self.counters["session_churn"] += int(churn)
             self.delta_s.record(delta_s)
+
+    # -- spans ---------------------------------------------------------------
+
+    def span(self, name: str, *, wave: bool = False, **attrs) -> ActiveSpan:
+        """A context manager that records one span into the process-wide
+        ring (see the module docstring); ``wave=True`` gives it, and the
+        spans opened inside it, a new wave id."""
+        return ActiveSpan(name, next(_WAVE_IDS) if wave else 0, attrs)
+
+    def record_span(
+        self, name: str, start_ns: int, end_ns: int, *, parent: ActiveSpan, **attrs
+    ) -> Span:
+        """Record a span whose start was stamped earlier (a request's queue
+        dwell) straight into the ring, as a child of ``parent`` in its wave;
+        no profiler annotation."""
+        span = Span(name, next(_SPAN_IDS), parent.span_id, parent.wave_id,
+                    start_ns, end_ns, attrs)
+        _RING.add(span)
+        return span
 
     # -- reading -------------------------------------------------------------
 

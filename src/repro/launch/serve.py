@@ -128,9 +128,10 @@ class SelectionResponse:
     """Answer to one request, plus where/how it was served.
 
     Latency accounting is truthful and decomposed: ``queue_s`` is how long
-    THIS request waited for co-travellers (submit -> its wave's dispatch
-    start), ``wave_s`` is the wave's dispatch wall time (shared by the
-    wave), and ``latency_s`` is their sum — what the client observed.  A
+    THIS request waited for co-travellers (its ``serve.queue`` span: submit
+    -> its wave's dispatch start), ``wave_s`` is the wave's ``serve.wave``
+    span (shared by the wave), and ``latency_s`` is their sum — what the
+    client observed.  A
     request that waited 500 ms for a 10 ms wave reports 510 ms, not 10.
     """
 
@@ -142,7 +143,7 @@ class SelectionResponse:
     backend: str  # gain-sweep backend that answered ("xla", "pallas-fl", ...)
     latency_s: float  # client-observed: queue_s + wave_s
     queue_s: float = 0.0  # submit -> wave dispatch start (this request's wait)
-    wave_s: float = 0.0  # wave dispatch wall time (shared by the wave)
+    wave_s: float = 0.0  # the wave's serve.wave span (shared by the wave)
     deadline_missed: bool = False  # delivered after the spec's deadline_s
     attempts: int = 1  # dispatch attempts this request survived (retries + 1)
     degraded: str | None = None  # "xla" / "single-device" when a breaker
@@ -158,8 +159,9 @@ class ServerStats:
     (count / sum / max), percentiles come from a fixed-size reservoir, so a
     long-lived server's accounting is O(1) in flush count.  ``summary()``
     keeps the historical keys (requests / waves / slots / padded_slots /
-    total_s / qps) and adds the latency-decomposition and backpressure
-    fields; ``snapshot()`` is the full structured tree.
+    total_s: the sum of wave times, queueing left out) and adds the
+    latency-decomposition and backpressure fields; ``snapshot()`` is the
+    full structured tree.
     """
 
     def __init__(self, metrics: ServerMetrics | None = None):
@@ -189,11 +191,6 @@ class ServerStats:
     def total_seconds(self) -> float:
         return float(self.metrics.wave_s.total)
 
-    @property
-    def qps(self) -> float:
-        t = self.total_seconds
-        return self.requests / t if t > 0 else 0.0
-
     def summary(self) -> dict:
         m = self.metrics
         return {
@@ -202,7 +199,6 @@ class ServerStats:
             "slots": self.slots,
             "padded_slots": self.padded_slots,
             "total_s": round(self.total_seconds, 4),
-            "qps": round(self.qps, 1),
             "wave_p50_s": round(m.wave_s.percentile(0.50), 4) if self.waves else 0.0,
             "wave_p99_s": round(m.wave_s.percentile(0.99), 4) if self.waves else 0.0,
             "queue_p50_s": round(m.queue_s.percentile(0.50), 4)
@@ -342,35 +338,37 @@ class SelectionServer:
         """
         from repro.launch.coalesce import resolve_padder
 
-        if not isinstance(spec, SelectionSpec):
-            raise TypeError(
-                f"submit_spec() takes a SelectionSpec, got {type(spec).__name__!r}"
+        with self.metrics.span("serve.submit") as sp:
+            if not isinstance(spec, SelectionSpec):
+                raise TypeError(
+                    f"submit_spec() takes a SelectionSpec, got {type(spec).__name__!r}"
+                )
+            resolve_padder(type(spec.fn))  # raises NotImplementedError if unsupported
+            defn = resolve_optimizer(spec.optimizer.name)
+            if not defn.batched_capable:
+                raise ValueError(
+                    f"optimizer {spec.optimizer.name!r} has no batched execution "
+                    f"hooks, so it cannot ride served waves; batched-capable "
+                    f"optimizers: {wave_capable_names()}"
+                )
+            if self.max_queue is not None and self.pending_count >= self.max_queue:
+                self.metrics.inc("rejections")
+                raise ServerOverloaded(
+                    f"pending queue is full ({self.pending_count}/{self.max_queue} "
+                    f"requests); flush, raise max_queue, or retry after a drain"
+                )
+            if rid is None:
+                rid = self._next_rid
+                self._next_rid += 1
+            sp.attrs["rid"] = rid
+            req = SelectionRequest(rid=rid, spec=spec)
+            key = group_key(req, n_multiple=self.n_multiple)
+            queue = self._queues.setdefault(key, [])
+            queue.append(req)
+            self.metrics.observe_enqueue(
+                group_label(req, n_multiple=self.n_multiple), len(queue)
             )
-        resolve_padder(type(spec.fn))  # raises NotImplementedError if unsupported
-        defn = resolve_optimizer(spec.optimizer.name)
-        if not defn.batched_capable:
-            raise ValueError(
-                f"optimizer {spec.optimizer.name!r} has no batched execution "
-                f"hooks, so it cannot ride served waves; batched-capable "
-                f"optimizers: {wave_capable_names()}"
-            )
-        if self.max_queue is not None and self.pending_count >= self.max_queue:
-            self.metrics.inc("rejections")
-            raise ServerOverloaded(
-                f"pending queue is full ({self.pending_count}/{self.max_queue} "
-                f"requests); flush, raise max_queue, or retry after a drain"
-            )
-        if rid is None:
-            rid = self._next_rid
-            self._next_rid += 1
-        req = SelectionRequest(rid=rid, spec=spec)
-        key = group_key(req, n_multiple=self.n_multiple)
-        queue = self._queues.setdefault(key, [])
-        queue.append(req)
-        self.metrics.observe_enqueue(
-            group_label(req, n_multiple=self.n_multiple), len(queue)
-        )
-        return rid
+            return rid
 
     def submit(
         self,
@@ -491,80 +489,104 @@ class SelectionServer:
         ) or None
         if degraded is not None:
             self.metrics.inc("fallbacks_total")
-        t0 = time.monotonic()
-        try:
-            faults.check(
-                "dispatch",
-                family=fam,
-                backend=primary,
-                wave_index=widx,
-                mesh=mesh is not None,
-                rids=tuple(r.rid for r in wave.requests),
-                label=wave.label,
-            )
-            # host-side backend resolution doubles as the "kernel" fault
-            # boundary (resolve_backend); also names the backend that
-            # actually answers after any degraded rewrite
-            name = backend_name(fns[0])
-            engine = BatchedEngine(
-                fns,
-                valid=wave.valid,
-                mesh=mesh,
-                batch_axis=self.batch_axis,
-                data_axis=self.data_axis,
-            )
-            results = engine.run(
-                wave.budgets,
-                wave.optimizer,
-                stop_if_zero=wave.stop_if_zero,
-                stop_if_negative=wave.stop_if_negative,
-                max_budget=wave.max_budget,
-            )
-        except Exception as e:
-            # attribute the failure to the path that was actually in play:
-            # kernel-site faults (and any error while a fused backend was
-            # live) charge the kernel breaker; dispatch errors on a mesh
-            # charge the mesh breaker
-            site = getattr(e, "site", None)
-            if site == "kernel":
-                self.breakers.record_failure((fam, "kernel"))
-            elif mesh is not None:
-                self.breakers.record_failure((fam, "mesh"))
-            elif primary != "xla" and not kernel_degraded:
-                self.breakers.record_failure((fam, "kernel"))
-            raise
-        if primary != "xla" and not kernel_degraded:
-            self.breakers.record_success((fam, "kernel"))
-        if mesh is not None:
-            self.breakers.record_success((fam, "mesh"))
-        t1 = time.monotonic()
-        wave_s = t1 - t0
         label = wave.label
-        self.metrics.observe_wave(
+        metrics = self.metrics
+        with metrics.span(
+            "serve.wave",
+            wave=True,
+            rids=tuple(r.rid for r in wave.requests),
+            B=wave.batch_size,
+            n_bucket=wave.n_bucket,
+            bytes_stacked=wave.bytes_stacked,
+            bytes_live=wave.bytes_live,
+        ) as sp:
+            queued = [
+                metrics.record_span(
+                    "serve.queue", req.enqueue_ns, sp.start_ns, parent=sp, rid=req.rid
+                )
+                for req in wave.requests
+            ]
+            try:
+                faults.check(
+                    "dispatch",
+                    family=fam,
+                    backend=primary,
+                    wave_index=widx,
+                    mesh=mesh is not None,
+                    rids=sp.attrs["rids"],
+                    label=label,
+                )
+                # host-side backend resolution doubles as the "kernel" fault
+                # boundary (resolve_backend); also names the backend that
+                # actually answers after any degraded rewrite
+                name = sp.attrs["backend"] = backend_name(fns[0])
+                with metrics.span("engine.build"):
+                    engine = BatchedEngine(
+                        fns,
+                        valid=wave.valid,
+                        mesh=mesh,
+                        batch_axis=self.batch_axis,
+                        data_axis=self.data_axis,
+                    )
+                results = engine.run(
+                    wave.budgets,
+                    wave.optimizer,
+                    stop_if_zero=wave.stop_if_zero,
+                    stop_if_negative=wave.stop_if_negative,
+                    max_budget=wave.max_budget,
+                    span=metrics.span,
+                )
+            except Exception as e:
+                # attribute the failure to the path that was actually in
+                # play: kernel-site faults (and any error while a fused
+                # backend was live) charge the kernel breaker; dispatch
+                # errors on a mesh charge the mesh breaker
+                site = getattr(e, "site", None)
+                if site == "kernel":
+                    self.breakers.record_failure((fam, "kernel"))
+                elif mesh is not None:
+                    self.breakers.record_failure((fam, "mesh"))
+                elif primary != "xla" and not kernel_degraded:
+                    self.breakers.record_failure((fam, "kernel"))
+                raise
+            if primary != "xla" and not kernel_degraded:
+                self.breakers.record_success((fam, "kernel"))
+            if mesh is not None:
+                self.breakers.record_success((fam, "mesh"))
+            with metrics.span("serve.demux"):
+                by_rid = wave.demux(results)
+                out = {
+                    req.rid: SelectionResponse(
+                        rid=req.rid,
+                        selection=by_rid[req.rid].as_list(),
+                        result=by_rid[req.rid],
+                        wave_size=len(wave.requests),
+                        n_bucket=wave.n_bucket,
+                        backend=name,
+                        latency_s=q.seconds,
+                        queue_s=q.seconds,
+                        degraded=degraded,
+                    )
+                    for req, q in zip(wave.requests, queued)
+                }
+        # the wave's time is its span's: known once the span has closed
+        wave_s, end_t = sp.seconds, sp.end_ns * 1e-9
+        metrics.observe_wave(
             label,
             wave_s,
             requests=len(wave.requests),
             slots=wave.batch_size,
             padded_slots=wave.n_padded_slots,
+            bytes_stacked=wave.bytes_stacked,
+            bytes_live=wave.bytes_live,
         )
-        by_rid = wave.demux(results)
-        out = {}
         for req in wave.requests:
-            queue_s = max(0.0, t0 - req.enqueue_t)
-            missed = req.deadline_t is not None and t1 > req.deadline_t
-            self.metrics.observe_served(label, queue_s, deadline_missed=missed)
-            out[req.rid] = SelectionResponse(
-                rid=req.rid,
-                selection=by_rid[req.rid].as_list(),
-                result=by_rid[req.rid],
-                wave_size=len(wave.requests),
-                n_bucket=wave.n_bucket,
-                backend=name,
-                latency_s=queue_s + wave_s,
-                queue_s=queue_s,
-                wave_s=wave_s,
-                deadline_missed=missed,
-                degraded=degraded,
+            resp = out[req.rid]
+            resp.wave_s = wave_s
+            resp.latency_s += wave_s
+            resp.deadline_missed = req.deadline_t is not None and end_t > req.deadline_t
+            metrics.observe_served(
+                label, resp.queue_s, deadline_missed=resp.deadline_missed
             )
         return out
 
@@ -588,20 +610,22 @@ class SelectionServer:
         if keys is None:
             keys = list(self._queues)
         waves: list[Wave] = []
-        for key in keys:
-            requests = self._queues.get(key)
-            if not requests:
-                continue
-            waves.extend(
-                waves_for_group(
-                    requests,
-                    max_wave=self.max_wave,
-                    n_multiple=self.n_multiple,
-                    b_multiple=self.b_multiple,
+        with self.metrics.span("serve.drain") as sp:
+            for key in keys:
+                requests = self._queues.get(key)
+                if not requests:
+                    continue
+                waves.extend(
+                    waves_for_group(
+                        requests,
+                        max_wave=self.max_wave,
+                        n_multiple=self.n_multiple,
+                        b_multiple=self.b_multiple,
+                    )
                 )
-            )
-        for key in keys:
-            self._queues.pop(key, None)
+            for key in keys:
+                self._queues.pop(key, None)
+            sp.attrs["waves"] = len(waves)
         undelivered: dict = {}
         if take_undelivered:
             undelivered, self._undelivered = self._undelivered, {}
@@ -674,7 +698,7 @@ class SelectionServer:
         terminal :class:`RequestFailed` when the budget is exhausted —
         ``max_attempts`` (``"quarantined"``) or wall-clock ``timeout_s``
         (``"timeout"``) — else None (the request may retry)."""
-        now = time.monotonic()
+        now = time.perf_counter()
         hist = self._attempts.setdefault(req.rid, [])
         hist.append(
             {
@@ -801,56 +825,58 @@ class SelectionServer:
         waves: list[Wave] = []
         failures: dict = {}
         retry_wait = 0.0
-        for key in list(keys):
-            requests = self._queues.get(key)
-            if not requests:
-                self._queues.pop(key, None)
-                continue
-            now = time.monotonic()
-            live = []
-            for req in requests:
-                pol = self._policy_for(req)
-                if pol.timeout_s is not None and now - req.enqueue_t >= pol.timeout_s:
-                    hist = self._attempts.pop(req.rid, [])
-                    failures[req.rid] = RequestFailed(req.rid, "timeout", hist)
-                else:
-                    live.append(req)
-            if not live:
-                self._queues.pop(key, None)
-                continue
-            try:
-                group_waves = waves_for_group(
-                    live,
-                    max_wave=self.max_wave,
-                    n_multiple=self.n_multiple,
-                    b_multiple=self.b_multiple,
-                )
-            except Exception as e:
-                self.metrics.inc("flush_errors")
-                keep = []
-                for req in live:
-                    term = self._note_attempt(req, e)
-                    if term is not None:
-                        failures[req.rid] = term
-                    else:
-                        keep.append(req)
-                if keep:
-                    self.metrics.inc("retries_total", len(keep))
-                    self._queues[key] = keep
-                    retry_wait = max(
-                        retry_wait,
-                        max(
-                            self._policy_for(r).backoff(
-                                len(self._attempts[r.rid]), seed=r.rid
-                            )
-                            for r in keep
-                        ),
-                    )
-                else:
+        with self.metrics.span("serve.drain") as sp:
+            for key in list(keys):
+                requests = self._queues.get(key)
+                if not requests:
                     self._queues.pop(key, None)
-                continue
-            waves.extend(group_waves)
-            self._queues.pop(key, None)
+                    continue
+                now = time.perf_counter()
+                live = []
+                for req in requests:
+                    pol = self._policy_for(req)
+                    if pol.timeout_s is not None and now - req.enqueue_t >= pol.timeout_s:
+                        hist = self._attempts.pop(req.rid, [])
+                        failures[req.rid] = RequestFailed(req.rid, "timeout", hist)
+                    else:
+                        live.append(req)
+                if not live:
+                    self._queues.pop(key, None)
+                    continue
+                try:
+                    group_waves = waves_for_group(
+                        live,
+                        max_wave=self.max_wave,
+                        n_multiple=self.n_multiple,
+                        b_multiple=self.b_multiple,
+                    )
+                except Exception as e:
+                    self.metrics.inc("flush_errors")
+                    keep = []
+                    for req in live:
+                        term = self._note_attempt(req, e)
+                        if term is not None:
+                            failures[req.rid] = term
+                        else:
+                            keep.append(req)
+                    if keep:
+                        self.metrics.inc("retries_total", len(keep))
+                        self._queues[key] = keep
+                        retry_wait = max(
+                            retry_wait,
+                            max(
+                                self._policy_for(r).backoff(
+                                    len(self._attempts[r.rid]), seed=r.rid
+                                )
+                                for r in keep
+                            ),
+                        )
+                    else:
+                        self._queues.pop(key, None)
+                    continue
+                waves.extend(group_waves)
+                self._queues.pop(key, None)
+            sp.attrs["waves"] = len(waves)
         undelivered: dict = {}
         if take_undelivered:
             undelivered, self._undelivered = self._undelivered, {}
@@ -945,15 +971,16 @@ class SelectionServer:
         return responses in request order.  Responses to requests enqueued
         earlier via ``submit`` ride the same flush and are held for the next
         ``flush`` call."""
-        specs = [
-            r if isinstance(r, SelectionSpec) else SelectionSpec(r[0], r[1])
-            for r in requests
-        ]
-        rids = [self.submit_spec(s) for s in specs]
-        out = self.flush()
-        mine = [out.pop(r) for r in rids]
-        self.hold_undelivered(out)
-        return mine
+        with self.metrics.span("serve.select"):
+            specs = [
+                r if isinstance(r, SelectionSpec) else SelectionSpec(r[0], r[1])
+                for r in requests
+            ]
+            rids = [self.submit_spec(s) for s in specs]
+            out = self.flush()
+            mine = [out.pop(r) for r in rids]
+            self.hold_undelivered(out)
+            return mine
 
 
 # ---------------------------------------------------------------------------

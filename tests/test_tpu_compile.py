@@ -177,3 +177,25 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
         - mem.alias_size_in_bytes
     )
     assert used < HBM_BYTES, f"{name} needs {used} bytes on one 16 GB chip"
+
+
+def test_served_fl_wave_names_its_kernel_fl_gains(one_chip, no_persistent_cache, monkeypatch):
+    """The served FL wave's program (the batched naive greedy over a wave of
+    10 classes) calls the sweep as a custom call whose instruction name is
+    the kernel's fixed name, ``fl_gains`` or ``fl_gains.<n>``, which the
+    trace's per-op table reads (not a name derived from a Python wrapper,
+    such as ``fl_gains_pallas.11``)."""
+    import re
+
+    import repro.kernels.ops as ops
+    from repro.core import FacilityLocation
+    from repro.core.optimizers.batched import _batched_naive
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)  # compile for the chip
+    sim = jax.ShapeDtypeStruct((B, U, N), jnp.float32, sharding=one_chip)
+    fns = FacilityLocation(sim=sim, n=N, use_kernel=True)
+    budgets = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    valid = jax.ShapeDtypeStruct((B, N), jnp.bool_, sharding=one_chip)
+    hlo = _batched_naive.lower(fns, 512, budgets, valid, True, True).compile().as_text()
+    kernels = re.findall(r"%([\w.-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    assert kernels and all(re.fullmatch(r"fl_gains(\.\d+)?", k) for k in kernels), kernels
