@@ -83,7 +83,9 @@ def tree_sum(x: jax.Array, axis: int) -> jax.Array:
 # tile-aligned whichever of its two minor axes XLA lays the summed axis out
 # on.  That matters: a greedy loop's column gathers make XLA keep a kernel
 # with its rows on lanes, and a block narrower than a lane tile would have
-# the loop copy the whole kernel back every step (compiled for a v5e).
+# the loop copy the whole kernel back every step (compiled for a v5e).  The
+# Pallas FL kernel reads the kernel in that layout, rows on lanes, and adds
+# its BLOCK-lane blocks of a row tile one after another.
 BLOCK = 128
 
 
@@ -107,8 +109,8 @@ def tiled_sum(
     """Sum ``terms(*operands)`` over its ``axis`` in the Pallas kernels'
     order: BLOCK-wide blocks added in sequence into a zero accumulator
     (:func:`add_blocks`), then :func:`tree_sum` of the accumulator.  The
-    operands' last partial block is zero-padded, as the kernels pad them;
-    terms of zero operands must be +0.
+    operands' last partial block is zero-padded, as the kernels pad or mask
+    them; terms of zero operands must be +0.
 
     ``terms`` is elementwise along the summed axis; operand ``k`` is sliced
     along its ``in_axes[k]``.  The blocks are static slices that XLA fuses

@@ -38,11 +38,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.common import DISTANCE_METRICS, NEG_INF, exact_self_distance
-from repro.kernels.fl_gains import _PAD_CM
 
 BU = 256  # represented-set rows per tile
 BN = 512  # candidates per tile
 BK = 512  # feature-contraction strip
+
+_PAD_CM = 3.0e38  # pad value for curmax: relu(s - huge) == 0 contributes nothing
 
 
 def _flmf_kernel(

@@ -95,6 +95,30 @@ def test_fl_gains_property(u, n, seed):
     assert (got >= -1e-6).all()  # gains of a monotone function
 
 
+@pytest.mark.parametrize("u", [1, 1003, 5000])
+def test_fl_gains_ragged_rows_sum_in_the_xla_order(u, rng):
+    """A row count that is no multiple of the row tile or of BLOCK is read
+    without padding: the kernel masks the last tile's rows past u (which
+    interpret mode fills with NaN), and the gains keep the XLA sweep's bits,
+    in the full sweep and the gathered subset."""
+    from repro.common import fl_gain_sums
+    from repro.kernels.fl_gains import fl_gains_at_pallas
+
+    n = 40
+    s = rng.uniform(0, 1, size=(u, n)).astype(np.float32)
+    cm = rng.uniform(0, 0.8, size=(u,)).astype(np.float32)
+    got = np.asarray(fl_gains_pallas(s, cm, interpret=True))
+    want = np.asarray(fl_gain_sums(jnp.asarray(s), jnp.asarray(cm)))
+    assert got.tobytes() == want.tobytes()
+    np.testing.assert_allclose(
+        got, np.asarray(ref.fl_gains_ref(jnp.asarray(s), jnp.asarray(cm))),
+        rtol=1e-5, atol=1e-4,
+    )
+    idx = np.array([n - 1, -1, 0, 7], np.int32)
+    at = np.asarray(fl_gains_at_pallas(s, cm, idx, interpret=True))
+    assert at[idx >= 0].tobytes() == got[idx[idx >= 0]].tobytes()
+
+
 def test_fl_function_kernel_path_matches_plain(rng):
     """FacilityLocation(use_kernel=True) routes gains through the Pallas op
     and must select the identical greedy set."""

@@ -17,10 +17,12 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every test worker imports
 this file.
 """
+import re
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.kernels.disp_gains import dmin_gains_pallas, dsum_gains_pallas
@@ -199,3 +201,96 @@ def test_served_fl_wave_names_its_kernel_fl_gains(one_chip, no_persistent_cache,
     hlo = _batched_naive.lower(fns, 512, budgets, valid, True, True).compile().as_text()
     kernels = re.findall(r"%([\w.-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
     assert kernels and all(re.fullmatch(r"fl_gains(\.\d+)?", k) for k in kernels), kernels
+
+
+COMPUTATION = re.compile(r"(?ms)^(?:ENTRY )?%([\w.-]+) [^\n]*\{\n(.*?)\n\}")
+
+
+def _called(body: str) -> list[str]:
+    """The computations an HLO computation's instructions call."""
+    names = re.findall(r"(?:calls|body|condition|to_apply)=%([\w.-]+)", body)
+    for group in re.findall(r"branch_computations=\{([^}]*)\}", body):
+        names += re.findall(r"%([\w.-]+)", group)
+    return names
+
+
+def _in_loops(hlo: str) -> set[str]:
+    """Every computation that runs inside a while loop's body."""
+    comps = dict(COMPUTATION.findall(hlo))
+    seen, todo = set(), re.findall(r"\bbody=%([\w.-]+)", hlo)
+    while todo:
+        name = todo.pop()
+        if name not in seen and name in comps:
+            seen.add(name)
+            todo += _called(comps[name])
+    return seen
+
+
+def _wave_hlo(optimizer: str, topo, one_chip) -> tuple[str, int, int]:
+    """The compiled served FL wave on the Pallas sweep, with the batch and
+    candidate widths of the kernel each device holds."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import FacilityLocation
+    from repro.core.optimizers.batched import _batched_lazy, _batched_naive
+    from repro.core.optimizers.distributed import FLShardRule, sharded_batched_greedy
+
+    if optimizer == "sharded":  # 2x2 (batch, data) mesh: a quarter each
+        mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("batch", "data"))
+
+        def on(*spec):
+            return NamedSharding(mesh, P(*spec))
+
+        wave = jax.jit(
+            lambda s, b, v: sharded_batched_greedy(
+                FLShardRule(use_kernel=True), (s,), b, v, max_budget=512, mesh=mesh
+            )
+        )
+        args = (
+            jax.ShapeDtypeStruct((B, U, N), jnp.float32, sharding=on("batch", None, "data")),
+            jax.ShapeDtypeStruct((B,), jnp.int32, sharding=on("batch")),
+            jax.ShapeDtypeStruct((B, N), jnp.bool_, sharding=on("batch", "data")),
+        )
+        return wave.lower(*args).compile().as_text(), B // 2, N // 2
+    sim = jax.ShapeDtypeStruct((B, U, N), jnp.float32, sharding=one_chip)
+    fns = FacilityLocation(sim=sim, n=N, use_kernel=True)
+    budgets = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    valid = jax.ShapeDtypeStruct((B, N), jnp.bool_, sharding=one_chip)
+    if optimizer == "naive":
+        lowered = _batched_naive.lower(fns, 512, budgets, valid, True, True)
+    else:
+        lowered = _batched_lazy.lower(fns, 512, budgets, valid, 16, True, True)
+    return lowered.compile().as_text(), B, N
+
+
+@pytest.mark.parametrize(
+    "optimizer, outside", [("naive", 1), ("lazy", 2), ("sharded", 1)]
+)
+def test_served_fl_wave_reads_its_kernel_in_the_loop_layout(
+    optimizer, outside, topo, one_chip, no_persistent_cache, monkeypatch
+):
+    """The served FL wave (10 classes of 5,000 rows, n-bucket 8,192, on the
+    Pallas sweep; on one chip, or on a 2x2 mesh with a quarter of the
+    stacked kernel on each) keeps its kernel in one layout through the
+    greedy loop: the sweep reads the loop's carry through a bitcast and the
+    curmax update gathers columns from the same carry, so no step pads or
+    copies a whole kernel.  The copy into that layout happens before the
+    loop: once for the naive wave; the lazy wave's bound sweep before its
+    loop takes a second one, since the loop keeps a buffer of its own."""
+    import repro.kernels.ops as ops
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)  # compile for the chip
+    hlo, b, n = _wave_hlo(optimizer, topo, one_chip)
+    in_loop = _in_loops(hlo)
+    assert in_loop, "no while loop in the wave"
+    whole = re.compile(
+        rf"(?m)^  (?:ROOT )?%[\w.-]+ = f32\[{b},(?:(?:{U}|5120),{n}|{n},(?:{U}|5120))\]"
+        r"\{[^}]*\} (pad|copy)\("
+    )
+    found = {"loop": [], "outside": []}
+    for name, body in COMPUTATION.findall(hlo):
+        where = "loop" if name in in_loop else "outside"
+        found[where] += whole.findall(body)
+    assert found["loop"] == [], found
+    assert "pad" not in found["outside"], found
+    assert len(found["outside"]) <= outside, found
