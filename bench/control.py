@@ -38,12 +38,8 @@ def control_readings(parts: dict, seed: int, precision: str) -> dict:
     generator = run.module(parts["bench_dir"] / "traffic", traffic["generator"])
     readings = []
     for _, x in generator.inputs(config, traffic, seed):
-        low = reference.kernel(x, config["metric"], precision)
-        picks, gains = jax.device_get(reference.greedy(low, config["budget"]))
-        del low
-        exact = reference.kernel(x, config["metric"])
-        readings.append(reference.compare(exact, picks, gains, config["budget"]))
-        del exact
+        picks, gains = jax.device_get(reference.greedy(x, config["metric"], config["budget"], precision))
+        readings.append(reference.compare(x, config["metric"], picks, gains, config["budget"]))
     return reference.worst(readings)
 
 

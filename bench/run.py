@@ -172,10 +172,9 @@ def check(loop, config: dict, limits: dict, failed: int) -> tuple[bool, dict]:
         )
     readings = []
     for key, answers in distinct.items():
-        S = reference.kernel(loop.inputs(key), config["metric"])
+        x = loop.inputs(key)
         for order, gains in answers.values():
-            readings.append(reference.compare(S, order, gains, config["budget"]))
-        del S
+            readings.append(reference.compare(x, config["metric"], order, gains, config["budget"]))
     numbers = reference.worst(readings)
     numbers["missing"] = failed if readings else max(1, failed)
     checks = {
