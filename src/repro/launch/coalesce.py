@@ -396,6 +396,8 @@ class Wave:
     n_bucket: int
     bytes_stacked: int  # nbytes of the padded fns' leaves, batch pads included
     bytes_live: int  # nbytes of the real requests' own unpadded fns' leaves
+    cols_stacked: int  # candidate columns swept: B x n_bucket, batch pads included
+    cols_live: int  # the real requests' own candidates: sum of their n
 
     @property
     def batch_size(self) -> int:
@@ -540,6 +542,8 @@ def waves_for_group(
                 # one group key: every padded fn has the same leaf shapes
                 bytes_stacked=b_total * _nbytes(fns[0]),
                 bytes_live=sum(_nbytes(r.spec.fn) for r in reqs),
+                cols_stacked=b_total * n_bucket,
+                cols_live=sum(r.spec.fn.n for r in reqs),
             )
         )
     return waves

@@ -17,6 +17,8 @@ Schema (``ServerMetrics.snapshot()``)::
         "padded_slots": int,    # batch-pad slots (pad waste)
         "bytes_stacked": int,   # nbytes of the waves' padded functions
         "bytes_live": int,      # nbytes of the real requests' own functions
+        "cols_stacked": int,    # candidate columns the waves sweep (B x n-bucket)
+        "cols_live": int,       # the real requests' own candidates (sum of n)
         "rejections": int,      # submits refused by backpressure
         "flush_errors": int,    # dispatch errors (FlushError raised)
         "requeued": int,        # requests re-enqueued after a flush error
@@ -171,6 +173,8 @@ _COUNTERS = (
     "padded_slots",
     "bytes_stacked",
     "bytes_live",
+    "cols_stacked",
+    "cols_live",
     "rejections",
     "flush_errors",
     "requeued",
@@ -361,6 +365,8 @@ class ServerMetrics:
         padded_slots: int,
         bytes_stacked: int = 0,
         bytes_live: int = 0,
+        cols_stacked: int = 0,
+        cols_live: int = 0,
     ) -> None:
         """One engine dispatch for ``label``'s group, ``wave_s`` long."""
         with self._lock:
@@ -370,6 +376,8 @@ class ServerMetrics:
             self.counters["padded_slots"] += padded_slots
             self.counters["bytes_stacked"] += bytes_stacked
             self.counters["bytes_live"] += bytes_live
+            self.counters["cols_stacked"] += cols_stacked
+            self.counters["cols_live"] += cols_live
             self.wave_s.record(wave_s)
             g = self._group_locked(label)
             g.waves += 1

@@ -499,6 +499,8 @@ class SelectionServer:
             n_bucket=wave.n_bucket,
             bytes_stacked=wave.bytes_stacked,
             bytes_live=wave.bytes_live,
+            cols_stacked=wave.cols_stacked,
+            cols_live=wave.cols_live,
         ) as sp:
             queued = [
                 metrics.record_span(
@@ -579,6 +581,8 @@ class SelectionServer:
             padded_slots=wave.n_padded_slots,
             bytes_stacked=wave.bytes_stacked,
             bytes_live=wave.bytes_live,
+            cols_stacked=wave.cols_stacked,
+            cols_live=wave.cols_live,
         )
         for req in wave.requests:
             resp = out[req.rid]

@@ -1,6 +1,6 @@
 """Spans of the served selection path (``launch/metrics.py``): nesting,
 parent and wave ids, the bounded process-wide ring, the pad-waste byte
-counters, the histograms fed from span durations, and the spans' place on
+and column counters, the histograms fed from span durations, and the spans' place on
 a ``jax.profiler`` trace's host plane."""
 import glob
 import os
@@ -10,7 +10,13 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import FacilityLocation, GraphCut, SelectionSpec, create_kernel
+from repro.core import (
+    FacilityLocation,
+    FacilityLocationMF,
+    GraphCut,
+    SelectionSpec,
+    create_kernel,
+)
 from repro.launch import metrics as metrics_mod
 from repro.launch.async_serve import AsyncSelectionServer
 from repro.launch.coalesce import SelectionRequest, waves_for_group
@@ -33,6 +39,15 @@ PARENT_OF = {
 def _fl(rng, n=24):
     x = rng.normal(size=(n, 8)).astype(np.float32)
     return FacilityLocation.from_kernel(np.asarray(create_kernel(x, metric="euclidean")))
+
+
+def _flmf(rng, n=24):
+    x = rng.normal(size=(n, 8)).astype(np.float32)
+    return FacilityLocationMF.from_features(x, metric="euclidean")
+
+
+def _pad_share(stacked: int, live: int) -> float:
+    return 1.0 - live / stacked
 
 
 def _tree(root_id):
@@ -154,6 +169,42 @@ def test_wave_bytes_equal_nbytes_by_hand(rng):
     recorded = [s for s in _tree(_last_select().span_id) if s.name == "serve.wave"]
     assert sum(s.attrs["bytes_stacked"] for s in recorded) == c["bytes_stacked"]
     assert sum(s.attrs["bytes_live"] for s in recorded) == c["bytes_live"]
+
+
+@pytest.mark.parametrize("family", ["fl", "flmf"])
+def test_wave_columns_count_every_swept_candidate(rng, family):
+    """cols_stacked is B x n_bucket, batch pads included; cols_live the real
+    requests' own n.  Dense FL pads no row, so its columns' pad share is its
+    bytes'; a FacilityLocationMF wave's is 1 - n / bucket, which its bytes
+    (one zero feature row a pad column) understate."""
+    make = _fl if family == "fl" else _flmf
+    reqs = [SelectionRequest(rid=i, spec=SelectionSpec(make(rng, 24), 3)) for i in range(2)]
+    (wave,) = waves_for_group(reqs, b_multiple=4)
+    assert (wave.cols_stacked, wave.cols_live) == (4 * 32, 2 * 24)
+    (alone,) = waves_for_group(reqs[:1])
+    assert (alone.cols_stacked, alone.cols_live) == (32, 24)
+    share = _pad_share(alone.cols_stacked, alone.cols_live)
+    assert share == 1 - 24 / 32
+    byte_share = _pad_share(alone.bytes_stacked, alone.bytes_live)
+    if family == "fl":
+        assert byte_share == share
+        assert _pad_share(wave.bytes_stacked, wave.bytes_live) == _pad_share(
+            wave.cols_stacked, wave.cols_live
+        )
+    else:
+        assert 0 < byte_share < share
+
+
+def test_wave_spans_and_counters_carry_the_columns(rng):
+    server = SelectionServer(max_wave=2)
+    server.select([SelectionSpec(_flmf(rng, 24), 3) for _ in range(3)])
+    c = server.metrics.snapshot()["counters"]
+    assert c["cols_stacked"] == 2 * 32 + 32
+    assert c["cols_live"] == 3 * 24
+    recorded = [s for s in _tree(_last_select().span_id) if s.name == "serve.wave"]
+    assert len(recorded) == 2
+    assert sum(s.attrs["cols_stacked"] for s in recorded) == c["cols_stacked"]
+    assert sum(s.attrs["cols_live"] for s in recorded) == c["cols_live"]
 
 
 def test_wave_s_and_queue_s_equal_their_span_durations(rng):
