@@ -5,9 +5,10 @@ ground-set sizes, budgets — while the batched engines want homogeneous,
 statically-shaped waves.  This module is the bridge:
 
 1. **pad**: each request's function is zero-padded along the candidate axis
-   to a power-of-two bucket size (zero rows/columns have zero marginal gain
-   for the supported families, so padding never changes the selection), with
-   a per-request ``valid`` row masking the padding;
+   to its n-bucket (:func:`bucket_size`: a ladder of eight steps per octave,
+   capped at the next power of two; zero rows/columns have zero marginal
+   gain for the supported families, so padding never changes the
+   selection), with a per-request ``valid`` row masking the padding;
 2. **group**: padded requests sharing (pytree structure, leaf shapes,
    optimizer, stop flags) coalesce into waves of at most ``max_wave``;
 3. **bucket budgets**: a wave's static loop bound is the power-of-two bucket
@@ -112,10 +113,25 @@ def next_pow2(x: int) -> int:
     return 1 if x <= 1 else 1 << (int(x) - 1).bit_length()
 
 
+# Steps per octave of the n-bucket ladder.  Eight keeps the pad a bucket
+# adds under n / 8 (from n = 1,024 up, where the step is not held at one
+# lane width) while letting an octave of mixed-n traffic split into at most
+# eight group keys, hence at most eight compiled programs.
+BUCKETS_PER_OCTAVE = 8
+_LANES = 128  # the smallest step: one TPU lane width
+
+
 def bucket_size(n: int, multiple: int = 1) -> int:
-    """Power-of-two bucket >= n, rounded up to a multiple (the mesh data-axis
-    size, so sharded waves always divide evenly)."""
-    b = next_pow2(n)
+    """The n-bucket of a ground set of ``n`` candidates: ``n`` rounded up to
+    a step of 1/``BUCKETS_PER_OCTAVE`` of its octave (at least one lane
+    width), never above the next power of two, then up to a multiple of
+    ``multiple`` (the mesh data-axis size, so sharded waves always divide
+    evenly).  From n = 4,096 up every bucket is a multiple of 512, the
+    candidate tile of the Pallas gain sweeps."""
+    n = max(int(n), 1)
+    octave = 1 << (n.bit_length() - 1)  # the largest power of two <= n
+    step = max(octave // BUCKETS_PER_OCTAVE, _LANES)
+    b = min(next_pow2(n), -(-n // step) * step)
     return -(-b // multiple) * multiple
 
 
@@ -362,7 +378,7 @@ def pad_function(fn, n_to: int):
 
     The registry is consulted even when no padding is needed: a family
     without a padder must fail the same way at every ground-set size, not
-    only when its n misses a power-of-two bucket.  This is also the
+    only when its n misses its bucket.  This is also the
     "padder" fault-injection boundary (``launch/faults.py``) — it fires
     even at exact bucket sizes, for the same any-size consistency reason.
     Materialization happens at flush time, so a padder fault aborts a

@@ -113,6 +113,34 @@ def test_bucket_size():
     assert bucket_size(3, multiple=4) == 4
     assert bucket_size(5, multiple=4) == 8  # pow2 already divisible
     assert bucket_size(2, multiple=3) == 3  # non-pow2 mesh axis
+    # the ladder: eight steps per octave, at least one lane width
+    assert bucket_size(5_000) == 5_120  # a CIFAR-10 class: 10 tiles of 512
+    assert bucket_size(50_000) == 53_248  # the CIFAR-10 pool: 104 tiles
+    assert bucket_size(1_100) == 1_152
+    assert bucket_size(300) == 384
+
+
+@pytest.mark.parametrize("multiple", [1, 2, 3, 4, 8])
+def test_bucket_size_ladder_properties(multiple):
+    """Over a range of n: a bucket never exceeds the power-of-two bucket
+    (rounded to the multiple), is a multiple of ``multiple``, pads under
+    1/8 from n = 1,024 up, takes at most eight values per octave, never
+    falls as n grows, and from n = 4,096 up is a whole number of the
+    Pallas sweeps' 512-candidate tiles (for a power-of-two multiple)."""
+    octaves = {}
+    prev = 0
+    for n in [*range(1, 20_000), *range(20_000, 140_000, 37)]:
+        b = bucket_size(n, multiple)
+        assert n <= b <= -(-next_pow2(n) // multiple) * multiple, n
+        assert b % multiple == 0, n
+        if n >= 1_024 and multiple == 1:
+            assert (b - n) / n < 1 / 8, n
+        if n >= 4_096 and 512 % multiple == 0:
+            assert b % 512 == 0, n
+        assert b >= prev, n
+        prev = b
+        octaves.setdefault((n - 1).bit_length(), set()).add(b)  # n in (2^k, 2^(k+1)]
+    assert max(len(bs) for bs in octaves.values()) <= 8, octaves
 
 
 @pytest.mark.parametrize(
@@ -139,6 +167,38 @@ def test_pad_function_preserves_selection_exactly(kind, rng):
     ref = naive_greedy(fn, 6, stop_zero, stop_neg)
     assert list(np.asarray(ref.order)) == list(np.asarray(got.order))
     np.testing.assert_array_equal(np.asarray(ref.gains), np.asarray(got.gains))
+
+
+@pytest.mark.parametrize("kind", ["fl", "flmf", "gc"])
+def test_served_off_power_of_two_bucket_bit_identical(kind):
+    """A request whose n lands in a bucket that is not a power of two (1,100
+    candidates padded to 1,152) is served bit for bit as unpadded sequential
+    solve: ids, gains and n_evals.  FacilityLocation pads columns only,
+    FacilityLocationMF pads its feature source's candidate rows, and
+    GraphCut pads its kernel square."""
+    from repro.core import FacilityLocationMF, solve
+
+    n, budget = 1_100, 8
+    rng = np.random.default_rng(17)
+    x = rng.normal(size=(n, 8)).astype(np.float32)
+    if kind == "flmf":
+        fn = FacilityLocationMF.from_features(x, metric="euclidean")
+    else:
+        S = np.asarray(create_kernel(x, metric="euclidean"))
+        fn = (
+            FacilityLocation.from_kernel(S)
+            if kind == "fl"
+            else GraphCut.from_kernel(S, lam=0.3)
+        )
+    spec = SelectionSpec(fn, budget)
+    (served,) = SelectionServer().select([spec])
+    assert served.n_bucket == bucket_size(n) == 1_152
+    want = solve(spec)
+    for field in ("order", "gains", "n_evals"):
+        assert (
+            np.asarray(getattr(served.result, field)).tobytes()
+            == np.asarray(getattr(want, field)).tobytes()
+        ), field
 
 
 def test_coalesce_groups_and_pads(rng):

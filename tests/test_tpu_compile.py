@@ -8,10 +8,11 @@ overruns, programs that do not fit the chip's 16 GB of HBM — at no chip
 time.  Nothing runs, so these tests say nothing about results or speed.
 
 Shapes are the CIFAR-10 per-class coreset deployment (10 classes of 5,000
-items, 512-wide embeddings): dense sweeps at the served n-bucket 8192 (FL
-keeps its 5,000 represented rows), the FL sweep also vmapped over a wave of
-10 classes, and matrix-free sweeps over the whole 50,000 x 512 pool (with
-and without the column ids of a symmetric source).
+items, 512-wide embeddings): dense sweeps at the served n-bucket of a class,
+``bucket_size(5000)`` = 5,120 (FL keeps its 5,000 represented rows), the FL
+sweep also vmapped over a wave of 10 classes, and matrix-free sweeps over
+the whole 50,000 x 512 pool (with and without the column ids of a
+symmetric source).
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every test worker imports
@@ -33,10 +34,11 @@ from repro.kernels.gc_gains import gc_gains_at_pallas, gc_gains_pallas
 from repro.kernels.gcmf_gains import gcmf_gains_at_pallas, gcmf_gains_pallas
 from repro.kernels.sc_gains import psc_gains_pallas, sc_gains_pallas
 from repro.kernels.similarity_kernel import similarity_pallas
+from repro.launch.coalesce import bucket_size
 
 HBM_BYTES = 16 * 10**9  # one TPU v5e chip
 U = 5_000  # represented rows of one class
-N = 8_192  # served n-bucket of a 5,000-item class
+N = bucket_size(U)  # served n-bucket of a 5,000-item class
 B = 10  # classes per served wave
 POOL = 50_000  # whole training set, matrix-free
 D = 512  # embedding width (ResNet-18 penultimate layer)
@@ -269,7 +271,7 @@ def _wave_hlo(optimizer: str, topo, one_chip) -> tuple[str, int, int]:
 def test_served_fl_wave_reads_its_kernel_in_the_loop_layout(
     optimizer, outside, topo, one_chip, no_persistent_cache, monkeypatch
 ):
-    """The served FL wave (10 classes of 5,000 rows, n-bucket 8,192, on the
+    """The served FL wave (10 classes of 5,000 rows, n-bucket 5,120, on the
     Pallas sweep; on one chip, or on a 2x2 mesh with a quarter of the
     stacked kernel on each) keeps its kernel in one layout through the
     greedy loop: the sweep reads the loop's carry through a bitcast and the
