@@ -2,7 +2,7 @@
 #
 #   make verify      pre-merge umbrella: test-fast + docs-check
 #   make test-fast   tier-1 suite (excludes @slow; the CI / pre-merge gate)
-#   make test-all    everything, including multi-device + heavy-arch tests
+#   make test-all    everything, including multi-device + million-point tests
 #   make bench       benchmark driver (paper tables) + batched-engine bench
 #   make bench-serve serving throughput sweep (wave size x mesh shape)
 #   make bench-diff  re-run the batched bench and flag >20% throughput

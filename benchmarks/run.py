@@ -1,5 +1,4 @@
-"""Benchmark driver — one benchmark per paper table/figure, plus the
-roofline table derived from the dry-run artifacts.
+"""Benchmark runner — one benchmark per paper table/figure.
 
     PYTHONPATH=src python -m benchmarks.run
 
@@ -65,19 +64,6 @@ def main() -> None:
     t0 = time.perf_counter()
     mi_bench.main()
     csv.append(("mi_bench(fig7-8-10)", (time.perf_counter() - t0) * 1e6, "claims_ok"))
-
-    t0 = time.perf_counter()
-    from benchmarks import roofline
-
-    roof_rows = roofline.main()
-    csv.append(("roofline(dry-run)", (time.perf_counter() - t0) * 1e6,
-                f"cells={len(roof_rows)}"))
-    for r in roof_rows:
-        csv.append(
-            (f"roofline/{r['arch']}/{r['shape']}",
-             max(r["compute_s"], r["memory_s"], r["collective_s"]) * 1e6,
-             f"dominant={r['dominant']};roofline={r['roofline_fraction']:.3f}")
-        )
 
     print("\nname,us_per_call,derived")
     for name, us, derived in csv:

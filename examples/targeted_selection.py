@@ -3,9 +3,10 @@ unlabeled-pool samples that match a *target* distribution using FLQMI —
 the query-only-kernel MI measure that needs just a (|Q| x |V|) kernel.
 
 Scenario: the model underperforms on two rare modes; we have a small query
-set from those modes and a large unlabeled pool. FLQMI picks pool items
-matching the target; we verify the precision of the retrieval and the
-eta trade-off.
+set from those modes and a large unlabeled pool (a seeded mixture of 8
+modes). FLQMI picks pool items matching the target; we check the precision
+of the retrieval across the eta trade-off, and that the sharded route (a
+unit ``batch x data`` mesh) returns the sequential answer bit for bit.
 
     PYTHONPATH=src python examples/targeted_selection.py
 """
@@ -14,57 +15,57 @@ import sys
 sys.path.insert(0, "src")
 
 import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.configs.base import get_config  # noqa: E402
-from repro.data.pipeline import SyntheticTokens, embed_examples  # noqa: E402
-from repro.data.selection import SelectorConfig, SubmodularSelector  # noqa: E402
-from repro.models.model import init_params  # noqa: E402
+from repro.core import FLQMI, SelectionSpec, create_kernel, solve  # noqa: E402
+
+N_MODES = 8
+
+
+def mixture(rng, centres, n):
+    """``n`` points around the seeded mode ``centres``, and their modes."""
+    modes = rng.integers(0, len(centres), n)
+    x = centres[modes] + rng.normal(size=(n, centres.shape[1]))
+    return x.astype(np.float32), modes
 
 
 def main():
-    cfg = get_config("qwen3-0.6b").reduced()
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    data = SyntheticTokens(cfg, seq_len=64, n_modes=8, seed=0)
+    rng = np.random.default_rng(0)
+    centres = rng.normal(scale=4.0, size=(N_MODES, 16))
 
     # unlabeled pool: 128 examples across all 8 modes
-    pool_idx = list(range(128))
-    pool_emb = embed_examples(cfg, params, data.batch(pool_idx))
+    pool, pool_modes = mixture(rng, centres, 128)
 
     # target: rare modes 2 and 5 (say the model underperforms there);
-    # queries are held-out examples of those modes (disjoint index range)
-    rare = {2, 5}
-    q_idx = [i for i in range(1000, 1100) if data.mode_of(i) in rare][:6]
-    q_emb = embed_examples(cfg, params, data.batch(q_idx))
+    # queries are held-out examples of those modes, not pool members
+    rare = [2, 5]
+    held_out, held_modes = mixture(rng, centres, 200)
+    queries = np.concatenate([held_out[held_modes == m][:3] for m in rare])
 
+    S_qv = create_kernel(queries, pool, metric="euclidean")
+    base_rate = np.isin(pool_modes, rare).mean()
     budget = 16
     for eta in (0.0, 1.0, 4.0):
-        sel = SubmodularSelector(
-            cfg,
-            SelectorConfig(objective="targeted", budget=budget, eta=eta,
-                           use_pallas_kernel=False),
-        )
-        chosen = sel.select(pool_emb, query_emb=q_emb)
-        hits = sum(1 for i in chosen if data.mode_of(pool_idx[i]) in rare)
+        res = solve(SelectionSpec(FLQMI.build(S_qv, eta=eta), budget))
+        chosen = np.asarray(res.order)
+        hits = int(np.isin(pool_modes[chosen[chosen >= 0]], rare).sum())
         print(f"eta={eta:4.1f}: {hits}/{budget} selected items are target-mode "
-              f"(pool base rate {2 / 8:.0%})")
+              f"(pool base rate {base_rate:.0%})")
+        if eta > 0:  # eta weighs the pool's match to Q; at 0 only Q's coverage counts
+            assert hits / budget > base_rate, (eta, hits)
 
-    # distributed variant of the same selection on a (1,1) mesh — the exact
-    # program the multi-pod dry-run lowers at 512 devices
-    from repro.core import create_kernel, FLQMI
-    from repro.core.optimizers.distributed import distributed_flqmi_greedy
-    from repro.launch.mesh import make_test_mesh
-
-    S_qv = create_kernel(q_emb, pool_emb, metric="euclidean")
-    fn = FLQMI.build(S_qv, eta=1.0)
-    mesh = make_test_mesh((1, 1))
-    order, gains = distributed_flqmi_greedy(
-        S_qv, np.asarray(fn.modular), budget, mesh
-    )
-    hits = sum(1 for i in np.asarray(order) if data.mode_of(int(i)) in rare)
-    print(f"distributed FLQMI: {hits}/{budget} target-mode "
-          f"(matches serial: {list(np.asarray(order)[:5])}...)")
+    # the same request on the sharded route: one (batch x data) mesh, here a
+    # unit one; bigger meshes shard the pool's columns over "data"
+    spec = SelectionSpec(FLQMI.build(S_qv, eta=1.0), budget)
+    mesh = jax.make_mesh((1, 1), ("batch", "data"))
+    (sharded,) = solve([spec], mesh=mesh)
+    sequential = solve(spec)
+    assert sharded.as_list() == sequential.as_list(), "sharded != sequential"
+    top = [i for i, _ in sharded.as_list()]
+    hits = int(np.isin(pool_modes[top], rare).sum())
+    print(f"sharded FLQMI: {hits}/{budget} target-mode, bit-identical to "
+          f"sequential (first picks {top[:5]})")
+    assert hits >= budget // 2, hits
 
 
 if __name__ == "__main__":

@@ -56,8 +56,6 @@ from repro.core.optimizers.constrained import (
     matroid_greedy,
 )
 from repro.core.optimizers.distributed import (
-    distributed_fl_greedy,
-    distributed_flqmi_greedy,
     register_shard_rule,
     shard_rule,
     sharded_batched_greedy,
@@ -162,8 +160,6 @@ __all__ = [
     "PartitionMatroid",
     "sieve_streaming",
     "threshold_greedy",
-    "distributed_fl_greedy",
-    "distributed_flqmi_greedy",
     "sharded_batched_greedy",
     "shard_rule",
     "register_shard_rule",

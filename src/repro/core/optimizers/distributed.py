@@ -1,40 +1,28 @@
-"""Multi-pod distributed partition greedy (DESIGN §2, §5).
+"""Sharded batched greedy: a B-query wave over a 2-D mesh (DESIGN §2, §5).
 
-Two layers live here:
+The batch axis is sharded over one mesh axis and every instance's candidate
+axis over another — ``jax.vmap`` over the local batch slice composed with a
+shard_map partition-greedy sweep.  Function families plug in through
+:class:`ShardRule` adapters (registry mirrors
+``backends.register_gain_backend``), and each shard's gain sweep routes
+through ``backends.full_sweep`` / ``backends.partial_sweep`` on a
+candidate-sliced local instance, so fused Pallas sweeps (full and
+gathered-subset) are reused per shard.  Two step programs share the
+adapters: :func:`sharded_batched_greedy` (naive full sweeps + O(1) winner
+election) and :func:`sharded_batched_lazy` (the eval-sparse bucketed lazy
+engine: merged stale-bound prefixes + sharded gathered subsets).
 
-1. The original per-function partition greedies (``distributed_fl_greedy``
-   and friends): the ground set (kernel columns) sharded over the
-   data-parallel mesh axes, the represented set (kernel rows) over the model
-   axis.
-2. The generic **sharded batched engine** (serving tentpole): a B-query wave
-   runs with the batch axis sharded over one mesh axis and every instance's
-   candidate axis sharded over another — ``jax.vmap`` over the local batch
-   slice composed with the shard_map partition-greedy sweep.  Function
-   families plug in through :class:`ShardRule` adapters (registry mirrors
-   ``backends.register_gain_backend``), and each shard's gain sweep routes
-   through ``backends.full_sweep`` / ``backends.partial_sweep`` on a
-   candidate-sliced local instance, so fused Pallas sweeps (full and
-   gathered-subset) are reused per shard.  Two step programs share the
-   adapters: :func:`sharded_batched_greedy` (naive full sweeps + O(1)
-   winner election) and :func:`sharded_batched_lazy` (the eval-sparse
-   bucketed lazy engine: merged stale-bound prefixes + sharded gathered
-   subsets).
+Each step of the naive program, per instance:
 
-For the original partition greedy, each step:
+  1. local gains              — the family's sweep over the resident columns
+  2. local argmax             — first-index tie-break inside the shard
+  3. pmax + pmin(index)       — O(1)-payload global winner election
+  4. masked psum of winner's  — one broadcast to update the memoized
+     column over the col axes   statistic (FL curmax, FB acc, ...)
 
-  1. local partial gains      — fused relu-reduction on the resident block
-  2. psum over the row axis   — full gains for the local candidate shard
-  3. local argmax             — first-index tie-break inside the shard
-  4. pmax + pmin(index)       — O(1)-payload global winner election
-  5. masked psum of winner's  — one (U_loc,)-sized broadcast to update the
-     column over the col axes   memoized curmax statistic
-
-The per-step collective payload is O(U / mesh_rows) + O(1), independent of
-the ground-set size — this is what makes billion-item selection feasible
-(the paper's engine is single-node).
-
-Works on any mesh: ``col_axes`` may span ("pod", "data") so a 512-chip
-2-pod mesh shards a billion-point ground set 32-ways per pod.
+The per-step collective payload is O(stat) + O(1), independent of the
+ground-set size.  ``col_axes`` may span several mesh axes; the public entry
+is ``solve(..., mesh=jax.make_mesh((b, d), ("batch", "data")))``.
 """
 from __future__ import annotations
 
@@ -55,202 +43,6 @@ def _flat_axis_index(axes: Sequence[str]) -> jax.Array:
     for a in axes:
         idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
-
-
-def distributed_fl_greedy(
-    sim: jax.Array,
-    budget: int,
-    mesh: jax.sharding.Mesh,
-    row_axes: Sequence[str] | None = ("model",),
-    col_axes: Sequence[str] = ("data",),
-    stop_if_zero: bool = True,
-):
-    """Facility-Location greedy over a 2-D sharded similarity kernel.
-
-    ``sim`` is the global (U, V) kernel; rows shard over ``row_axes`` (or are
-    replicated when None), columns over ``col_axes``.  Returns
-    (order, gains): (budget,) global indices and gains, replicated.
-    """
-    row_axes = tuple(row_axes) if row_axes else ()
-    col_axes = tuple(col_axes)
-    in_spec = P(row_axes if row_axes else None, col_axes)
-
-    @partial(
-        jax.shard_map,
-        mesh=mesh,
-        in_specs=(in_spec,),
-        out_specs=(P(), P()),
-        check_vma=False,
-    )
-    def run(S_block):
-        U_loc, V_loc = S_block.shape
-        col_off = _flat_axis_index(col_axes) * V_loc
-        curmax = jnp.zeros((U_loc,), S_block.dtype)
-
-        def body(i, carry):
-            curmax, selected, order, gains, done = carry
-            part = jnp.maximum(S_block - curmax[:, None], 0.0).sum(axis=0)
-            g = jax.lax.psum(part, row_axes) if row_axes else part
-            g = jnp.where(selected, NEG_INF, g)
-            lbi = jnp.argmax(g)
-            lbg = g[lbi]
-            gbest = jax.lax.pmax(lbg, col_axes)
-            cand = jnp.where(lbg >= gbest, col_off + lbi, _INT_MAX)
-            winner = jax.lax.pmin(cand, col_axes)  # lowest index wins ties
-            stop = done | (stop_if_zero & (gbest <= 0.0))
-            take = ~stop
-            is_mine = (winner >= col_off) & (winner < col_off + V_loc)
-            wl = jnp.clip(winner - col_off, 0, V_loc - 1)
-            col = jnp.where(is_mine, S_block[:, wl], 0.0)
-            col = jax.lax.psum(col, col_axes)  # broadcast winner column
-            curmax = jnp.where(take, jnp.maximum(curmax, col), curmax)
-            selected = selected | (take & is_mine & (jnp.arange(V_loc) == wl))
-            order = order.at[i].set(jnp.where(take, winner, -1))
-            gains = gains.at[i].set(jnp.where(take, gbest, 0.0))
-            return curmax, selected, order, gains, stop
-
-        carry = (
-            curmax,
-            jnp.zeros((V_loc,), bool),
-            jnp.full((budget,), -1, jnp.int32),
-            jnp.zeros((budget,), jnp.float32),
-            jnp.zeros((), bool),
-        )
-        _, _, order, gains, _ = jax.lax.fori_loop(0, budget, body, carry)
-        return order, gains
-
-    return run(sim)
-
-
-def distributed_stochastic_fl_greedy(
-    sim: jax.Array,
-    budget: int,
-    mesh: jax.sharding.Mesh,
-    key: jax.Array,
-    sample_per_shard: int = 1024,
-    row_axes: Sequence[str] | None = ("model",),
-    col_axes: Sequence[str] = ("data",),
-):
-    """Stochastic-greedy variant of the partition greedy (§Perf-3 hillclimb).
-
-    Each round, every column-shard group samples ``sample_per_shard`` of its
-    unselected candidates (same sample within a column group — the PRNG key
-    folds in only the round and the column index, so the row-wise partial
-    gains stay psum-compatible) and the sweep touches only those columns:
-    HBM traffic per round drops from |V_loc| to sample_per_shard columns
-    (~64x here) at stochastic-greedy's usual <1% objective cost.
-
-    Also the straggler-mitigation path (DESIGN §6): a shard that misses a
-    round only removes its sample from that round's union."""
-    row_axes = tuple(row_axes) if row_axes else ()
-    col_axes = tuple(col_axes)
-    in_spec = P(row_axes if row_axes else None, col_axes)
-
-    @partial(
-        jax.shard_map,
-        mesh=mesh,
-        in_specs=(in_spec, P()),
-        out_specs=(P(), P()),
-        check_vma=False,
-    )
-    def run(S_block, key):
-        U_loc, V_loc = S_block.shape
-        s = min(sample_per_shard, V_loc)
-        col_idx = _flat_axis_index(col_axes)
-        col_off = col_idx * V_loc
-        curmax = jnp.zeros((U_loc,), S_block.dtype)
-
-        def body(i, carry):
-            curmax, selected, order, gains = carry
-            subkey = jax.random.fold_in(jax.random.fold_in(key, i), col_idx)
-            z = jnp.where(selected, -1.0, jax.random.uniform(subkey, (V_loc,)))
-            cand = jax.lax.top_k(z, s)[1]  # (s,) random unselected columns
-            cols = S_block[:, cand]  # (U_loc, s)
-            part = jnp.maximum(cols - curmax[:, None], 0.0).sum(axis=0)
-            g = jax.lax.psum(part, row_axes) if row_axes else part
-            g = jnp.where(selected[cand], NEG_INF, g)
-            bi = jnp.argmax(g)
-            lbg = g[bi]
-            lbi = cand[bi]
-            gbest = jax.lax.pmax(lbg, col_axes)
-            cand_g = jnp.where(lbg >= gbest, col_off + lbi, _INT_MAX)
-            winner = jax.lax.pmin(cand_g, col_axes)
-            is_mine = (winner >= col_off) & (winner < col_off + V_loc)
-            wl = jnp.clip(winner - col_off, 0, V_loc - 1)
-            col = jnp.where(is_mine, S_block[:, wl], 0.0)
-            col = jax.lax.psum(col, col_axes)
-            curmax = jnp.maximum(curmax, col)
-            selected = selected | (is_mine & (jnp.arange(V_loc) == wl))
-            order = order.at[i].set(winner)
-            gains = gains.at[i].set(gbest)
-            return curmax, selected, order, gains
-
-        carry = (
-            curmax,
-            jnp.zeros((V_loc,), bool),
-            jnp.full((budget,), -1, jnp.int32),
-            jnp.zeros((budget,), jnp.float32),
-        )
-        _, _, order, gains = jax.lax.fori_loop(0, budget, body, carry)
-        return order, gains
-
-    return run(sim, key)
-
-
-def distributed_flqmi_greedy(
-    sim_qv: jax.Array,
-    modular: jax.Array,
-    budget: int,
-    mesh: jax.sharding.Mesh,
-    col_axes: Sequence[str] = ("data",),
-    eta: float = 1.0,
-):
-    """FLQMI targeted selection with the query kernel replicated (|Q| small)
-    and the ground set column-sharded — the production configuration for
-    targeted data selection at pre-training scale."""
-    col_axes = tuple(col_axes)
-
-    @partial(
-        jax.shard_map,
-        mesh=mesh,
-        in_specs=(P(None, col_axes), P(col_axes)),
-        out_specs=(P(), P()),
-        check_vma=False,
-    )
-    def run(Sq_block, mod_block):
-        nq, V_loc = Sq_block.shape
-        col_off = _flat_axis_index(col_axes) * V_loc
-        curmax = jnp.zeros((nq,), Sq_block.dtype)
-
-        def body(i, carry):
-            curmax, selected, order, gains = carry
-            g = jnp.maximum(Sq_block - curmax[:, None], 0.0).sum(axis=0) + mod_block
-            g = jnp.where(selected, NEG_INF, g)
-            lbi = jnp.argmax(g)
-            lbg = g[lbi]
-            gbest = jax.lax.pmax(lbg, col_axes)
-            cand = jnp.where(lbg >= gbest, col_off + lbi, _INT_MAX)
-            winner = jax.lax.pmin(cand, col_axes)
-            is_mine = (winner >= col_off) & (winner < col_off + V_loc)
-            wl = jnp.clip(winner - col_off, 0, V_loc - 1)
-            col = jnp.where(is_mine, Sq_block[:, wl], 0.0)
-            col = jax.lax.psum(col, col_axes)
-            curmax = jnp.maximum(curmax, col)
-            selected = selected | (is_mine & (jnp.arange(V_loc) == wl))
-            order = order.at[i].set(winner)
-            gains = gains.at[i].set(gbest)
-            return curmax, selected, order, gains
-
-        carry = (
-            curmax,
-            jnp.zeros((V_loc,), bool),
-            jnp.full((budget,), -1, jnp.int32),
-            jnp.zeros((budget,), jnp.float32),
-        )
-        _, _, order, gains = jax.lax.fori_loop(0, budget, body, carry)
-        return order, gains
-
-    return run(sim_qv, modular)
 
 
 # ---------------------------------------------------------------------------
@@ -320,7 +112,7 @@ class ShardRule:
 class FLShardRule(ShardRule):
     """FacilityLocation: columns sharded, rows (represented set) replicated;
     curmax is replicated and updated via a psum broadcast of the winner's
-    column — the same O(U) payload as ``distributed_fl_greedy``."""
+    column — an O(U) payload per step."""
 
     use_kernel: bool = False
 
@@ -720,8 +512,8 @@ class LogDetShardRule(ShardRule):
 class _FLInfoShardRule(ShardRule):
     """Shared shape for the FL-family information measures: query-side rows
     replicated, candidate columns sharded, ``curmax`` replicated and updated
-    by a psum broadcast of the winner's column — the
-    ``distributed_flqmi_greedy`` configuration generalized.  Subclasses
+    by a psum broadcast of the winner's column (|Q| small, so the query
+    kernel stays resident on every shard).  Subclasses
     rebuild the measure on the local column slice; the sweep then routes
     through ``backends.full_sweep`` so the class's own ``gains`` runs."""
 

@@ -1,23 +1,6 @@
 import numpy as np
 import pytest
 
-# the largest reduced-arch configs dominate test_archs wall time; they stay
-# covered by `make test-all` but are cut from the tier-1 fast suite
-_HEAVY_ARCHS = (
-    "jamba-1.5-large-398b",
-    "whisper-small",
-    "deepseek-v2-236b",
-    "kimi-k2-1t-a32b",
-)
-
-
-def pytest_collection_modifyitems(config, items):
-    for item in items:
-        if "test_archs.py" in item.nodeid and any(
-            a in item.nodeid for a in _HEAVY_ARCHS
-        ):
-            item.add_marker(pytest.mark.slow)
-
 
 @pytest.fixture
 def rng():
@@ -27,13 +10,3 @@ def rng():
     alphabetical file (and data-sensitive checks flaked).  A fresh generator
     per test keeps each test's data a pure function of its own draws."""
     return np.random.default_rng(0)
-
-
-def make_points(rng, n, d=8):
-    return rng.normal(size=(n, d)).astype(np.float32)
-
-
-def make_dist(rng, n, d=8):
-    x = make_points(rng, n, d)
-    d2 = ((x[:, None, :] - x[None, :, :]) ** 2).sum(-1)
-    return np.sqrt(np.maximum(d2, 0)).astype(np.float32)

@@ -38,31 +38,22 @@ _EXAMPLES = sorted(p.name for p in (ROOT / "examples").glob("*.py"))
 
 
 def test_examples_are_all_covered():
-    """Every example file has a smoke check below (fast or slow tier)."""
-    assert set(_EXAMPLES) == set(_FAST_EXAMPLES) | set(_SLOW_EXAMPLES)
+    """Every example file has a smoke check below."""
+    assert set(_EXAMPLES) == set(_FAST_EXAMPLES)
 
 
 _FAST_EXAMPLES = [
     "quickstart.py",
+    "coreset_training.py",
     "targeted_selection.py",
     "guided_summarization.py",
     "serving.py",
 ]
-# coreset_training drives a real training loop (selection + baseline arms,
-# ~25 min on this CPU) — covered by `make test-all`
-_SLOW_EXAMPLES = ["coreset_training.py"]
 
 
 @pytest.mark.parametrize("example", _FAST_EXAMPLES)
 def test_example_runs(example):
     r = _run([sys.executable, f"examples/{example}"], timeout=300)
-    assert r.returncode == 0, f"{example} failed:\n{r.stdout}\n{r.stderr}"
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("example", _SLOW_EXAMPLES)
-def test_example_runs_slow(example):
-    r = _run([sys.executable, f"examples/{example}"], timeout=3600)
     assert r.returncode == 0, f"{example} failed:\n{r.stdout}\n{r.stderr}"
 
 

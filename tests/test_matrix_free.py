@@ -15,7 +15,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from conftest import make_points
+from _points import make_points
 from repro.core import (
     FacilityLocation,
     FacilityLocationMF,

@@ -295,6 +295,21 @@ def test_server_bit_identical_to_maximize_loop(rng):
     assert s["requests"] == 12 and s["waves"] >= 3 and s["total_s"] > 0
 
 
+def test_selection_server_serves(rng):
+    """launch/serve.py front door: a mixed batch of requests comes back with
+    correct per-request selections."""
+    server = SelectionServer()
+    fns = []
+    for n in (20, 28):
+        x = rng.normal(size=(n, 6)).astype(np.float32)
+        S = np.asarray(create_kernel(x, metric="euclidean"))
+        fns.append(FacilityLocation.from_kernel(S))
+    responses = server.select([(fns[0], 4), (fns[1], 6)])
+    for fn, budget, resp in zip(fns, (4, 6), responses):
+        assert resp.selection == maximize(fn, budget)
+    assert server.stats.requests == 2
+
+
 def test_server_coalesces_same_shape_requests(rng):
     """Same-family same-bucket requests ride one wave (the serving win)."""
     server = SelectionServer(max_wave=8)
