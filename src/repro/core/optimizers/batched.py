@@ -12,8 +12,10 @@ Heterogeneity is expressed with padding masks rather than shape polymorphism:
   pass ``valid`` (B, n) — padded candidates are masked to -inf and never
   selected, and ``n_evals`` counts only the live candidates, so a padded
   instance reports the same count it would sequentially;
-- different budgets: pass a per-instance budget vector; the engine runs to
-  max(budgets) internally and freezes an instance once its budget is spent.
+- different budgets: pass a per-instance budget vector; an instance
+  freezes once its budget is spent.  The naive engine stops at max(budgets)
+  even under a larger (bucketed) ``max_budget``, which only sets the width
+  of the output; the lazy and sharded engines run to ``max_budget``.
 
 The per-instance results are bit-identical to a Python loop of single
 ``maximize`` calls (same sweep -> argmax -> update ordering, same stopping
@@ -87,10 +89,22 @@ def stack_functions(fns: Sequence) -> object:
 @partial(jax.jit, static_argnums=(1, 4, 5))
 def _batched_naive(fns, max_budget, budgets, valid, stop_if_zero, stop_if_negative):
     # per-instance behaviour is greedy._naive_impl itself — the bit-identical
-    # contract with sequential naive_greedy holds by construction
+    # contract with sequential naive_greedy holds by construction.  The loop
+    # stops at the wave's largest live budget: ``steps`` is computed outside
+    # the vmap, so it stays unbatched and lowers to a while loop whose
+    # predicate is one scalar (a batched predicate would select the whole
+    # carry every step).  ``max_budget`` stays the static output width, so
+    # waves of different budget mixes still share one compiled program.
+    steps = jnp.minimum(jnp.max(budgets), max_budget)
     return jax.vmap(
         lambda fn, b, v: _naive_impl(
-            fn, max_budget, stop_if_zero, stop_if_negative, budget_i=b, valid=v
+            fn,
+            max_budget,
+            stop_if_zero,
+            stop_if_negative,
+            budget_i=b,
+            valid=v,
+            steps=steps,
         )
     )(fns, budgets, valid)
 
@@ -142,6 +156,8 @@ class BatchedEngine:
         self.batch_axis = batch_axis
         self.data_axis = data_axis
         self._stacked = None
+        # greedy steps the last enqueued loop runs (see enqueue)
+        self.steps_run: int | None = None
         if mesh is None:
             self._stacked = stack_functions(fns)
         else:
@@ -228,9 +244,11 @@ class BatchedEngine:
         carries its validated hyperparameters, and the registry supplies the
         batched / sharded execution hook — an optimizer without one is
         rejected here with the batched-capable set named, never mid-trace.
-        ``max_budget`` optionally raises the static loop bound above
-        max(budgets) — serving uses bucketed bounds so waves with different
-        budget mixes share one compiled program.
+        ``max_budget`` optionally raises the static output width above
+        max(budgets) — serving uses bucketed widths so waves with different
+        budget mixes share one compiled program.  The naive loop still stops
+        at max(budgets); the others run to ``max_budget``.  Either way the
+        step count is left in ``self.steps_run``.
         """
         opt = OptimizerSpec(optimizer) if not isinstance(optimizer, OptimizerSpec) else optimizer
         defn = resolve_optimizer(opt.name)
@@ -265,6 +283,11 @@ class BatchedEngine:
                 f"{'sharded' if self.mesh is not None else 'batched'} "
                 f"execution; batched-capable optimizers: {wave_capable_names()}"
             )
+        # the single-device naive loop stops at the largest live budget;
+        # every other loop runs to the bucket
+        self.steps_run = (
+            max(budgets) if opt.name == "NaiveGreedy" and not sharded else max_budget
+        )
         if sharded:
             order, gains, evals, value = hook(
                 self.rule,

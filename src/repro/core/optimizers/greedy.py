@@ -53,11 +53,17 @@ def _naive_impl(
     stop_if_negative: bool,
     budget_i=None,
     valid=None,
+    steps=None,
 ) -> GreedyResult:
     """Single implementation behind :func:`naive_greedy` AND the batched
-    engine: ``budget_i`` (dynamic per-instance budget) and ``valid`` (padding
-    mask) are None for the plain single-query path — both are trace-time
-    decisions, so the None case lowers to exactly the unmasked program."""
+    engine: ``budget_i`` (dynamic per-instance budget), ``valid`` (padding
+    mask) and ``steps`` (the loop's traced trip count, at most ``budget``)
+    are None for the plain single-query path — all are trace-time
+    decisions, so the None case lowers to exactly the unmasked program.
+
+    ``budget`` is the width of ``order`` / ``gains``.  With ``steps`` the
+    loop stops after that many steps; the slots past it keep their initial
+    -1 / 0, which is what a step past ``budget_i`` writes anyway."""
     n = fn.n
     state = fn.init_state()
     # n_evals counts LOGICAL oracle evaluations: a padded instance (served
@@ -94,7 +100,9 @@ def _naive_impl(
         jnp.zeros((), jnp.int32),
         jnp.zeros((), bool),
     )
-    state, selected, order, gains, evals, _ = jax.lax.fori_loop(0, budget, body, carry)
+    state, selected, order, gains, evals, _ = jax.lax.fori_loop(
+        0, budget if steps is None else steps, body, carry
+    )
     return GreedyResult(order=order, gains=gains, n_evals=evals, value=gains.sum())
 
 

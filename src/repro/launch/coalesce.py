@@ -11,9 +11,11 @@ statically-shaped waves.  This module is the bridge:
    selection), with a per-request ``valid`` row masking the padding;
 2. **group**: padded requests sharing (pytree structure, leaf shapes,
    optimizer, stop flags) coalesce into waves of at most ``max_wave``;
-3. **bucket budgets**: a wave's static loop bound is the power-of-two bucket
-   of its largest budget, so waves with different budget mixes reuse one
-   compiled program (instances freeze once their own budget is spent);
+3. **bucket budgets**: a wave's ``max_budget`` is the power-of-two bucket
+   of its largest budget: the width of its output and part of its compile
+   key, so waves with different budget mixes reuse one compiled program.
+   The naive loop runs only to the wave's largest live budget (a traced
+   trip count); instances freeze once their own budget is spent;
 4. **pad the batch**: when serving on a mesh, the wave's batch dimension is
    padded to a multiple of the batch-axis size with budget-0 copies of the
    first instance (they finish in zero steps and are dropped at demux).
@@ -405,7 +407,7 @@ class Wave:
     fns: list  # padded instances; len >= len(requests) (batch pads at tail)
     valid: np.ndarray  # (B, n_bucket) bool
     budgets: list[int]  # per-slot budgets; 0 for batch-pad slots
-    max_budget: int  # static loop bound (pow2 bucket of the largest budget)
+    max_budget: int  # output width and compile key (pow2 bucket of the largest budget)
     optimizer: OptimizerSpec  # shared by the wave (hyperparameters included)
     stop_if_zero: bool
     stop_if_negative: bool
@@ -479,7 +481,7 @@ def group_key(req: SelectionRequest, *, n_multiple: int = 1) -> tuple:
     structure + leaf shapes, the (hashable) OptimizerSpec — hyperparameters
     ride along without being enumerated — and the stop flags.  Budgets and
     deadlines deliberately do NOT key: waves mix budgets under one bucketed
-    loop bound, and a deadline shapes flush *scheduling*, never wave
+    output width, and a deadline shapes flush *scheduling*, never wave
     membership.
     """
     fn = req.fn  # the spec's backend choice applied

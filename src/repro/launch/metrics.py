@@ -19,6 +19,8 @@ Schema (``ServerMetrics.snapshot()``)::
         "bytes_live": int,      # nbytes of the real requests' own functions
         "cols_stacked": int,    # candidate columns the waves sweep (B x n-bucket)
         "cols_live": int,       # the real requests' own candidates (sum of n)
+        "steps_run": int,       # greedy steps the waves' loops ran
+        "steps_bucket": int,    # the waves' budget buckets (output widths)
         "rejections": int,      # submits refused by backpressure
         "flush_errors": int,    # dispatch errors (FlushError raised)
         "requeued": int,        # requests re-enqueued after a flush error
@@ -175,6 +177,8 @@ _COUNTERS = (
     "bytes_live",
     "cols_stacked",
     "cols_live",
+    "steps_run",
+    "steps_bucket",
     "rejections",
     "flush_errors",
     "requeued",
@@ -367,6 +371,8 @@ class ServerMetrics:
         bytes_live: int = 0,
         cols_stacked: int = 0,
         cols_live: int = 0,
+        steps_run: int = 0,
+        steps_bucket: int = 0,
     ) -> None:
         """One engine dispatch for ``label``'s group, ``wave_s`` long."""
         with self._lock:
@@ -378,6 +384,8 @@ class ServerMetrics:
             self.counters["bytes_live"] += bytes_live
             self.counters["cols_stacked"] += cols_stacked
             self.counters["cols_live"] += cols_live
+            self.counters["steps_run"] += steps_run
+            self.counters["steps_bucket"] += steps_bucket
             self.wave_s.record(wave_s)
             g = self._group_locked(label)
             g.waves += 1

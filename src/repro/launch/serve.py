@@ -501,6 +501,7 @@ class SelectionServer:
             bytes_live=wave.bytes_live,
             cols_stacked=wave.cols_stacked,
             cols_live=wave.cols_live,
+            steps_bucket=wave.max_budget,
         ) as sp:
             queued = [
                 metrics.record_span(
@@ -538,6 +539,7 @@ class SelectionServer:
                     max_budget=wave.max_budget,
                     span=metrics.span,
                 )
+                sp.attrs["steps_run"] = engine.steps_run
             except Exception as e:
                 # attribute the failure to the path that was actually in
                 # play: kernel-site faults (and any error while a fused
@@ -583,6 +585,8 @@ class SelectionServer:
             bytes_live=wave.bytes_live,
             cols_stacked=wave.cols_stacked,
             cols_live=wave.cols_live,
+            steps_run=sp.attrs["steps_run"],
+            steps_bucket=wave.max_budget,
         )
         for req in wave.requests:
             resp = out[req.rid]

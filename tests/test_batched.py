@@ -362,3 +362,82 @@ def test_feature_based_batch_width_bit_stable(concave, rng):
                 np.asarray(getattr(got.result, field)).tobytes()
                 == np.asarray(getattr(want, field)).tobytes()
             ), field
+
+
+# -- the naive loop stops at the wave's largest live budget -------------------
+
+
+def _sized(family, n, rng):
+    x = rng.normal(size=(n, 6)).astype(np.float32)
+    S = np.asarray(create_kernel(x, metric="euclidean"))
+    if family == "fl":
+        return FacilityLocation.from_kernel(S)
+    return GraphCut.from_kernel(S, lam=0.3)
+
+
+@pytest.mark.parametrize("n", [24, 5], ids=["budgets", "stops-early"])
+@pytest.mark.parametrize("family", ["fl", "gc"])
+def test_naive_wave_under_a_budget_bucket_equals_sequential(family, n):
+    """A naive wave whose budgets differ inside one bucket (3 and 7 under
+    max_budget 8, plus a budget-0 batch pad) returns, instance for
+    instance, the ids, gains and n_evals of sequential naive_greedy.  At
+    n 5 the budget-7 instance stops on a zero gain before its budget, so
+    the wave's last steps run for nobody."""
+    rng = np.random.default_rng(19)
+    fns = [_sized(family, n, rng) for _ in range(2)]
+    fns.append(fns[0])  # batch pad
+    budgets = [3, 7, 0]
+    engine = BatchedEngine(fns)
+    launched = engine.enqueue(budgets, "NaiveGreedy", max_budget=8)
+    assert engine.steps_run == 7
+    res = launched[0]
+    order, gains, evals = (np.asarray(a) for a in (res.order, res.gains, res.n_evals))
+    for i, (fn, b) in enumerate(zip(fns[:2], budgets)):
+        want = naive_greedy(fn, b)
+        assert order[i, :b].tobytes() == np.asarray(want.order).tobytes(), b
+        assert gains[i, :b].tobytes() == np.asarray(want.gains).tobytes(), b
+        assert int(evals[i]) == int(want.n_evals), b
+    # past each budget, the batch pad's whole row included: the initial -1 / 0
+    for i, b in enumerate(budgets):
+        assert (order[i, b:] == -1).all() and (gains[i, b:] == 0).all(), b
+    assert int(evals[2]) == 0
+    if n == 5:
+        assert (np.asarray(naive_greedy(fns[1], 7).order) >= 0).sum() < 7
+
+
+def test_served_naive_wave_runs_to_its_largest_budget(monkeypatch):
+    """Budgets 3 and 7 bucket to 8; the wave's loop sweeps 7 times, and its
+    serve.wave span and the server's counters say 7 steps of a bucket of 8."""
+    import jax
+
+    from repro.core import SelectionSpec
+    from repro.core.optimizers import backends
+    from repro.launch.metrics import spans
+    from repro.launch.serve import SelectionServer
+
+    sweeps = []
+
+    class Counting:
+        name = "xla"
+
+        def full_sweep(self, fn, state):
+            jax.debug.callback(lambda: sweeps.append(1))  # unbatched: once a step
+            return fn.gains(state)
+
+    monkeypatch.setitem(backends._REGISTRY, FacilityLocation, lambda fn: Counting())
+    jax.clear_caches()  # trace the wave afresh, through the counting backend
+    try:
+        rng = np.random.default_rng(7)
+        server = SelectionServer()
+        out = server.select(
+            [SelectionSpec(_sized("fl", 20, rng), b) for b in (3, 7)]
+        )
+    finally:
+        jax.clear_caches()  # no later test reuses the counting program
+    assert [len(r.selection) for r in out] == [3, 7]
+    assert out[0].wave_size == 2
+    assert len(sweeps) == 7
+    (wave,) = [s for s in spans() if s.name == "serve.wave"][-1:]
+    assert (wave.attrs["steps_run"], wave.attrs["steps_bucket"]) == (7, 8)
+    c = server.metrics.snapshot()["counters"]
+    assert (c["steps_run"], c["steps_bucket"]) == (7, 8)
